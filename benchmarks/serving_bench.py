@@ -145,6 +145,7 @@ import numpy as np
 
 from benchmarks.common import Row
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import kv_quant
 from repro.models import model as M
 from repro.serving.engine import EngineStats, ServingEngine
@@ -715,8 +716,8 @@ def run(smoke: bool = False, json_path: str | None = None,
                  f"outputs_identical=True"))
 
     # -- 8. scale-out: sharded dispatch + prefix-affinity replica router -----
-    # Rung 1 (tensor scale-up) runs in a SUBPROCESS: jax fixes the device
-    # topology at import time and this process owns one CPU device, so
+    # Rung 1 (tensor scale-up) runs in a CPU SUBPROCESS: jax fixes the
+    # device topology at import time and this process owns one device, so
     # ``benchmarks.sharded_probe`` forces 2 host devices before its jax
     # import, runs one trace through a meshless engine and one whose
     # paged pool (payload + SCLAD scales) is shard_map-sharded over the
@@ -727,7 +728,10 @@ def run(smoke: bool = False, json_path: str | None = None,
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in ("src", env.get("PYTHONPATH", "")) if p)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # The probe runs on the CPU whatever this process was given: a child
+    # that reached for the accelerator would contend with this parent,
+    # which already holds it.
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)  # the probe forces its own device count
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.sharded_probe",
@@ -1079,6 +1083,7 @@ def main():
     ap.add_argument("--spec-k", type=int, default=4,
                     help="max draft tokens per lane per verify pass")
     args = ap.parse_args()
+    enable_compile_cache()
     for r in run(smoke=args.smoke, json_path=args.json,
                  kv_dtype=args.kv_dtype, spec_decode=args.spec_decode,
                  spec_k=args.spec_k):

@@ -2,8 +2,10 @@
 
     PYTHONPATH=src python examples/quickstart.py [--arch tinyllama-1.1b]
 
-Uses the reduced (smoke) config so it runs on CPU in seconds; drop
-``.reduced()`` on a TPU pod to get the full model under the production mesh.
+Uses the reduced (smoke) config so it runs on the CPU in seconds, with
+the Pallas kernels in interpret mode.  The full-width model on one TPU v5e
+chip, through the same engine and kernels, is ``python chip_smoke.py``
+(see README.md).
 """
 import argparse
 

@@ -38,8 +38,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 
     def body(i, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(i * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(i * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(i * block_k, block_k), :]
+        v = v_ref[pl.ds(i * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T  # (block_q, block_k)
         if causal:
             k_pos = i * block_k + jax.lax.broadcasted_iota(
@@ -49,7 +49,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + p.astype(v.dtype) @ v
+        acc = acc * corr + jnp.dot(p.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32)
         return acc, m_new, l
 
     acc0 = jnp.zeros((block_q, d), jnp.float32)

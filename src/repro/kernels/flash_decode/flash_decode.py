@@ -11,8 +11,10 @@ CC-MEM contract (PAPER.md §CC-MEM).  Two cache layouts share the math:
     batch sit at different sequence offsets.
   * ``paged_flash_decode`` — the serving engine's block-pool layout
     (N, bs, Hk, D) addressed through per-lane block tables
-    (``serving.paged.BlockStore``).  The grid is (batch, kv_heads,
-    table_width) and the block table rides the scalar-prefetch channel
+    (``serving.paged.BlockStore``).  The grid is (batch, table_width),
+    one program per pool block with all its kv heads (the TPU lowering
+    needs the block's last two dims, (Hk, D), whole), and the block table
+    rides the scalar-prefetch channel
     (``PrefetchScalarGridSpec``): the index map resolves ``tables[b, i]``
     BEFORE the program body runs, so each program's K/V block is DMA'd
     straight from the shared pool — no dense per-lane copy of the pool is
@@ -35,10 +37,13 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _pv_dtype(v):
-    """MXU-friendly dtype for the probs @ V matmul: the cache dtype, except
-    f8 (too coarse for probabilities) which is computed in bf16."""
-    return jnp.bfloat16 if v.dtype == jnp.float8_e4m3fn else v.dtype
+def _pv(p, v):
+    """probs @ V on the MXU with an fp32 accumulator (the TPU lowering
+    requires 32-bit accumulation).  Operands are cast to the cache dtype,
+    except f8 (too coarse for probabilities), which is computed in bf16."""
+    dt = jnp.bfloat16 if v.dtype == jnp.float8_e4m3fn else v.dtype
+    return jnp.dot(p.astype(dt), v.astype(dt),
+                   preferred_element_type=jnp.float32)
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
@@ -51,8 +56,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
 
     def body(i, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(i * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(i * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(i * block_k, block_k), :]
+        v = v_ref[pl.ds(i * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T  # (rep, block_k)
         pos = i * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
@@ -61,7 +66,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + p.astype(_pv_dtype(v)) @ v.astype(_pv_dtype(v))
+        acc = acc * corr + _pv(p, v)
         return acc, m_new, l
 
     # Only blocks below `length` contribute.
@@ -109,29 +114,42 @@ def flash_decode(q, k_cache, v_cache, lengths, *, block_k: int = 128,
     return out.reshape(B, H, D)
 
 
+def _head_col(x, h: int):
+    """Column ``h`` of a (rows, Hk) tile as (rows, 1), by a lane select and
+    reduce (exact: one non-zero term) rather than a lane slice."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == h, x, 0.0), axis=1, keepdims=True)
+
+
 def _paged_decode_kernel(lens_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
                          bs: int, block_k: int, sm_scale: float,
                          quantized: bool):
-    """One program = one pool block of one (row, kv_head) pair.
+    """One program = one pool block of one row, all of its KV heads.
 
     lens_ref (B,) / tbl_ref (B, T): scalar-prefetch SMEM (the table also
-    drives the K/V index maps); q_ref (rep, D); k_ref/v_ref (bs, D): THIS
-    grid step's pool block, already resolved through the table; o_ref
-    (rep, D).  acc/m/l: VMEM scratch carrying the online softmax across
-    the T (innermost, sequential) grid dimension.
+    drives the K/V index maps); q_ref (Hk, rep, D); k_ref/v_ref
+    (bs, Hk, D): THIS grid step's pool block, already resolved through the
+    table; o_ref (Hk, rep, D).  acc/m/l: VMEM scratch carrying each head's
+    online softmax across the T (innermost, sequential) grid dimension.
 
-    ``quantized`` (SCLAD pool): two extra (bs, 1) fp32 refs ks/vs carry the
-    block's per-position scales (resolved through the SAME table walk), and
-    the load path expands payload * scale in fp32 before the usual math —
-    compressed bytes are all that crosses HBM; compute sees dense values.
+    The block holds every KV head: the TPU lowering needs a block's last
+    two dims to be (8, 128)-aligned or whole, and (Hk, D) is whole where
+    a single-head (1, D) slab is not.  Heads are a static loop in VMEM.
+
+    ``quantized`` (SCLAD pool): two extra (bs, Hk) fp32 refs ks/vs carry the
+    block's per-position-per-head scales (resolved through the SAME table
+    walk), and the load path expands payload * scale in fp32 before the
+    usual math — compressed bytes are all that crosses HBM; compute sees
+    dense values.
     """
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         ks_ref = vs_ref = None
         o_ref, acc_ref, m_ref, l_ref = rest
-    b, i = pl.program_id(0), pl.program_id(2)
-    T = pl.num_programs(2)
+    b, i = pl.program_id(0), pl.program_id(1)
+    T = pl.num_programs(1)
+    Hk = k_ref.shape[1]
     length = lens_ref[b]
 
     @pl.when(i == 0)
@@ -144,34 +162,33 @@ def _paged_decode_kernel(lens_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     # (their table entries point at the trash block for unallocated tails).
     @pl.when(i * bs < length)
     def _block():
-        q = q_ref[...].astype(jnp.float32) * sm_scale
-        for s0 in range(0, bs, block_k):  # static sub-tiling of the block
-            k = k_ref[s0:s0 + block_k, :]
-            v = v_ref[s0:s0 + block_k, :]
-            if quantized:
-                # Load-as-Dense: (bs', D) payload * (bs', 1) scale in fp32,
-                # then ROUNDED to the compute dtype — the exact cast chain
-                # of ``kv_quant.dequantize(..., q.dtype)`` in the jnp
-                # reference, so both implementations score bitwise-equal
-                # dense values and the fp path's ref/kernel greedy
-                # bit-identity carries over to quantized pools.
-                k = (k.astype(jnp.float32)
-                     * ks_ref[s0:s0 + block_k, :]).astype(q_ref.dtype)
-                v = (v.astype(jnp.float32)
-                     * vs_ref[s0:s0 + block_k, :]).astype(q_ref.dtype)
-            s = q @ k.astype(jnp.float32).T  # (rep, block_k)
-            pos = i * bs + s0 + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            s = jnp.where(pos < length, s, NEG_INF)
-            m = m_ref[...]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l_ref[...] = l_ref[...] * corr \
-                + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * corr \
-                + p.astype(_pv_dtype(v)) @ v.astype(_pv_dtype(v))
-            m_ref[...] = m_new
+        for h in range(Hk):
+            q = q_ref[h].astype(jnp.float32) * sm_scale  # (rep, D)
+            for s0 in range(0, bs, block_k):  # static sub-tiling
+                k = k_ref[s0:s0 + block_k, h, :]
+                v = v_ref[s0:s0 + block_k, h, :]
+                if quantized:
+                    # Load-as-Dense: (bs', D) payload * (bs', 1) scale in
+                    # fp32, then ROUNDED to the compute dtype — the exact
+                    # cast chain of ``kv_quant.dequantize(..., q.dtype)``
+                    # in the jnp reference, so both implementations score
+                    # bitwise-equal dense values.
+                    k = (k.astype(jnp.float32) * _head_col(
+                        ks_ref[s0:s0 + block_k, :], h)).astype(q_ref.dtype)
+                    v = (v.astype(jnp.float32) * _head_col(
+                        vs_ref[s0:s0 + block_k, :], h)).astype(q_ref.dtype)
+                s = q @ k.astype(jnp.float32).T  # (rep, block_k)
+                pos = i * bs + s0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_k), 1)
+                s = jnp.where(pos < length, s, NEG_INF)
+                m = m_ref[h]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+                l_ref[h] = l_ref[h] * corr \
+                    + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * corr + _pv(p, v)
+                m_ref[h] = m_new
 
     @pl.when(i == T - 1)
     def _done():
@@ -201,9 +218,9 @@ def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, *,
                   same value against any pool block size.
     kv_scales:    optional (k_scale, v_scale) (N, bs, Hk) fp32 — the SCLAD
                   quantized pool's per-position-per-head scales.  They ride
-                  the same table-walk BlockSpecs as the payload (one (bs, 1)
-                  scale tile per program) and the dequant multiply is fused
-                  into the block-streaming loop in VMEM.
+                  the same table-walk BlockSpecs as the payload (one
+                  (bs, Hk) scale tile per program) and the dequant multiply
+                  is fused into the block-streaming loop in VMEM.
 
     Returns (B, H, D).  KV bytes are read exactly once per token, block by
     block through the table — never gathered into a per-lane dense copy.
@@ -219,38 +236,30 @@ def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, *,
     qt = q.reshape(B, Hk, rep, D)
     quantized = kv_scales is not None
 
-    pool_blk = pl.BlockSpec((None, bs, None, D),
-                            lambda b, h, i, lens, tbl: (tbl[b, i], 0, h, 0))
-    # Scales get a trailing singleton ((N, bs, Hk) -> (N, bs, Hk, 1), a
-    # layout-preserving view) so their table-walked tile is 2D (bs, 1).
-    scale_blk = pl.BlockSpec((None, bs, None, 1),
-                             lambda b, h, i, lens, tbl: (tbl[b, i], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((None, None, rep, D),
-                     lambda b, h, i, lens, tbl: (b, h, 0, 0)),
-        # The pool is indexed THROUGH the prefetched table: each grid
-        # step DMAs exactly one shared block for one kv head.
-        pool_blk,
-        pool_blk,
-    ]
+    # The pool is indexed THROUGH the prefetched table: each grid step
+    # DMAs exactly one shared block (all kv heads) of one row.
+    pool_blk = pl.BlockSpec((None, bs, Hk, D),
+                            lambda b, i, lens, tbl: (tbl[b, i], 0, 0, 0))
+    row_blk = pl.BlockSpec((None, Hk, rep, D),
+                           lambda b, i, lens, tbl: (b, 0, 0, 0))
+    in_specs = [row_blk, pool_blk, pool_blk]
     inputs = [jnp.asarray(lengths, jnp.int32),
               jnp.asarray(block_tables, jnp.int32), qt, k_pool, v_pool]
     if quantized:
-        k_scale, v_scale = kv_scales
+        scale_blk = pl.BlockSpec((None, bs, Hk),
+                                 lambda b, i, lens, tbl: (tbl[b, i], 0, 0))
         in_specs += [scale_blk, scale_blk]
-        inputs += [k_scale.astype(jnp.float32)[..., None],
-                   v_scale.astype(jnp.float32)[..., None]]
+        inputs += [s.astype(jnp.float32) for s in kv_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # lengths, block_tables
-        grid=(B, Hk, T),
+        grid=(B, T),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, rep, D),
-                               lambda b, h, i, lens, tbl: (b, h, 0, 0)),
+        out_specs=row_blk,
         scratch_shapes=[
-            pltpu.VMEM((rep, D), jnp.float32),  # acc
-            pltpu.VMEM((rep, 1), jnp.float32),  # running max
-            pltpu.VMEM((rep, 1), jnp.float32),  # running denom
+            pltpu.VMEM((Hk, rep, D), jnp.float32),  # acc
+            pltpu.VMEM((Hk, rep, 1), jnp.float32),  # running max
+            pltpu.VMEM((Hk, rep, 1), jnp.float32),  # running denom
         ],
     )
     out = pl.pallas_call(

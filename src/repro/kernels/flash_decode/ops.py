@@ -88,9 +88,11 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_tables=None,
     while S % bk:  # largest divisor of S at most block_k
         bk -= 1
     if bk < 8 and bk < S:
-        # Degenerate tiling (e.g. prime S): a token-at-a-time kernel loop
-        # would be far slower than the fused reference — use that instead.
-        return decode_ref(q, k_cache, v_cache, lengths)
+        # Degenerate tiling (e.g. prime S): refuse rather than quietly
+        # serve the reference where the kernel was asked for.
+        raise ValueError(
+            f"contiguous cache length {S} has no tile in [8, {block_k}] "
+            f"that divides it; size the cache to a multiple of 8")
     return flash_decode(q, k_cache, v_cache, lengths, block_k=bk,
                         interpret=interpret)
 
@@ -121,6 +123,5 @@ def _sharded_paged_decode(q, k_cache, v_cache, lengths, block_tables,
                                 kernel=kernel, block_k=block_k,
                                 kv_scales=tuple(scales) or None)
 
-    return sharding.shard_map(body, mesh, in_specs=tuple(in_specs),
-                              out_specs=sp["out_decode"],
-                              check_vma=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=sp["out_decode"], check_vma=False)(*args)

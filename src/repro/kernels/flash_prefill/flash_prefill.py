@@ -10,7 +10,8 @@ PER LAYER, a dense per-lane copy of the shared KV pool
 (B, S, S+T*bs) mask, then round-tripped the chunk's compacted K/V through
 HBM again as a separate ``.at[].set`` scatter.  Here instead:
 
-  * the grid is (batch, kv_heads, T_read + W): the first ``T_read`` steps
+  * the grid is (batch, T_read + W), one program per step taking every
+    kv head of its block: the first ``T_read`` steps
     walk the lane's block table on the scalar-prefetch channel
     (``PrefetchScalarGridSpec`` — the index map resolves ``tbl[b, i]``
     BEFORE the body runs), streaming cached context K/V block by block
@@ -25,7 +26,7 @@ HBM again as a separate ``.at[].set`` scatter.  Here instead:
   * the last ``W`` steps SCATTER the chunk's new-token K/V into the pool
     through the table (``input_output_aliases`` pins the pool in place):
     each step merges one destination block — old rows kept, new rows
-    placed by a one-hot (bs, S) matmul that folds the left-pad compaction
+    one contiguous slice of the chunk that folds the left-pad compaction
     (dest ``start + j`` reads padded row ``j + pad``) — so compacted K/V
     never round-trips through HBM as a separate scatter.
 
@@ -35,9 +36,9 @@ can never be observed by a concurrently-read shared block; steps whose
 block index clamps past the row's real write span re-merge identical
 content (idempotent) or copy the old block through unchanged.
 
-CI exercises the kernel in Pallas interpret mode (CPU); the BlockSpecs /
-grid are the TPU deployment artifacts and real-TPU validation remains
-open (see ROADMAP).
+The tests run the kernel in Pallas interpret mode on the CPU and compile
+it for a v5e (``tests/test_tpu_compile.py``); ``chip_smoke.py`` checks
+its output and its pool writes against the jnp reference on the chip.
 """
 from __future__ import annotations
 
@@ -49,31 +50,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_decode.flash_decode import _head_col, _pv
+
 NEG_INF = -1e30
-
-
-def _pv_dtype(v):
-    """MXU-friendly dtype for the probs @ V matmul: the operand dtype,
-    except f8 (too coarse for probabilities) which is computed in bf16."""
-    return jnp.bfloat16 if v.dtype == jnp.float8_e4m3fn else v.dtype
 
 
 def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                     kp_ref, vp_ref, *rest, bs: int, prefix: int,
                     t_read: int, sm_scale: float, kv_dtype=None):
-    """One program = one grid step of one (row, kv_head) pair.
+    """One program = one grid step of one row, all of its KV heads.
 
     len/start (B,) and tbl (B, T): scalar-prefetch SMEM (the table also
-    drives the pool index maps); q_ref (S*rep, D); kn/vn_ref (S, D): the
-    chunk's rotated K/V for THIS kv head; kp/vp_ref (bs, D): this step's
-    pool block resolved through the table — cached context on read steps,
-    the scatter destination's old content on write steps; o_ref (S*rep, D);
-    ko/vo_ref (bs, D): the (aliased) pool block being written back.
-    acc/m/l: VMEM scratch carrying the online softmax across the
-    (innermost, sequential) grid dimension.
+    drives the pool index maps); q_ref (Hk, S*rep, D); kn/vn_ref
+    (bs + S + bs, Hk, D): the chunk's rotated K/V between ``bs`` zero rows
+    on each side; kp/vp_ref (bs, Hk, D): this
+    step's pool block resolved through the table — cached context on read
+    steps, the scatter destination's old content on write steps; o_ref
+    (Hk, S*rep, D); ko/vo_ref (bs, Hk, D): the (aliased) pool block being
+    written back.  acc/m/l: VMEM scratch carrying each head's online
+    softmax across the (innermost, sequential) grid dimension.  Heads are
+    a static loop: a pool block carries all of them because the TPU
+    lowering needs a block's last two dims (Hk, D) whole.
 
     ``kv_dtype`` ("int8"/"fp8"; None = fp pool) switches on the SCLAD
-    layout: ksp/vsp_ref and kso/vso_ref carry the (bs, 1) per-position
+    layout: ksp/vsp_ref and kso/vso_ref carry the (bs, Hk) per-position
     scale tiles riding the same table walk.  Context loads expand
     payload * scale in fp32; the chunk phase fake-quantizes its own K/V
     (matching what the scatter will store, so in-chunk and from-pool
@@ -89,11 +89,12 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         ksp_ref = vsp_ref = kso_ref = vso_ref = None
         o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref = rest
         qm = None
-    b, i = pl.program_id(0), pl.program_id(2)
-    n_i = pl.num_programs(2)
+    b, i = pl.program_id(0), pl.program_id(1)
+    n_i = pl.num_programs(1)
     T = tbl_ref.shape[1]
-    S, D = kn_ref.shape
-    rows = q_ref.shape[0]
+    Hk, D = kn_ref.shape[1:]
+    S = kn_ref.shape[0] - 2 * bs
+    rows = q_ref.shape[1]
     rep = rows // S
     P = S - prefix
     length = len_ref[b]
@@ -123,16 +124,16 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def online_update(s, v):
-        """Fold scores s (rows, K) and values v (K, D) into acc/m/l."""
-        m = m_ref[...]
+    def online_update(h, s, v):
+        """Fold head h's scores s (rows, K) and values v (K, D) into
+        acc/m/l."""
+        m = m_ref[h]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr \
-            + p.astype(_pv_dtype(v)) @ v.astype(_pv_dtype(v))
-        m_ref[...] = m_new
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + _pv(p, v)
+        m_ref[h] = m_new
 
     # Context phase: blocks wholly at/beyond the row's cached length are
     # dead (their table entries point at the trash block); context is
@@ -140,84 +141,104 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     # whole chunk (all chunk positions are causally after it).
     @pl.when((i < t_read) & (i * bs < start))
     def _ctx():
-        q = q_ref[...].astype(jnp.float32) * sm_scale
-        k = kp_ref[...].astype(jnp.float32)
-        v = vp_ref[...]
-        if kv_dtype is not None:
-            # Load-as-Dense: (bs, D) payload * (bs, 1) scale in fp32,
-            # then ROUNDED to the compute dtype — the reference's
-            # ``kv_quant.dequantize(..., q.dtype)`` cast chain, so both
-            # implementations attend to bitwise-equal dense values.
-            k = (k * ksp_ref[...]).astype(q_ref.dtype) \
-                .astype(jnp.float32)
-            v = (v.astype(jnp.float32) * vsp_ref[...]).astype(q_ref.dtype)
-        s = q @ k.T  # (rows, bs)
         pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(pos < start, s, NEG_INF)
-        online_update(s, v)
+        for h in range(Hk):
+            q = q_ref[h].astype(jnp.float32) * sm_scale
+            k = kp_ref[:, h, :].astype(jnp.float32)
+            v = vp_ref[:, h, :]
+            if kv_dtype is not None:
+                # Load-as-Dense: (bs, D) payload * (bs, 1) scale in fp32,
+                # then ROUNDED to the compute dtype — the reference's
+                # ``kv_quant.dequantize(..., q.dtype)`` cast chain, so both
+                # implementations attend to bitwise-equal dense values.
+                k = (k * _head_col(ksp_ref[...], h)).astype(q_ref.dtype) \
+                    .astype(jnp.float32)
+                v = (v.astype(jnp.float32) * _head_col(vsp_ref[...], h)
+                     ).astype(q_ref.dtype)
+            s = q @ k.T  # (rows, bs)
+            online_update(h, jnp.where(pos < start, s, NEG_INF), v)
 
     # In-chunk self-attention: causal over this call's tokens with pad
     # keys dropped — the mask the pre-kernel path materialized densely,
     # rebuilt here from iota against the start/length scalars.
     @pl.when(i == t_read)
     def _chunk():
-        q = q_ref[...].astype(jnp.float32) * sm_scale
-        k = kn_ref[...].astype(jnp.float32)
-        v = vn_ref[...]
-        if kv_dtype is not None:
-            # Attend to the chunk's K/V as quantized — identical to how a
-            # later chunk / decode step reads it back from the pool.  The
-            # compute-dtype round-trip matches ``kv_quant.fake_quant``
-            # (which returns x.dtype) bitwise.
-            k = fake_quant(k).astype(kn_ref.dtype).astype(jnp.float32)
-            v = fake_quant(v.astype(jnp.float32)).astype(vn_ref.dtype)
-        s = q @ k.T  # (rows, S)
         qpos = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // rep
         kpos = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         real = (kpos < prefix) | (kpos >= prefix + pad)
-        s = jnp.where((kpos <= qpos) & real, s, NEG_INF)
-        online_update(s, v)
+        visible = (kpos <= qpos) & real
+        for h in range(Hk):
+            q = q_ref[h].astype(jnp.float32) * sm_scale
+            k = kn_ref[bs:bs + S, h, :].astype(jnp.float32)
+            v = vn_ref[bs:bs + S, h, :]
+            if kv_dtype is not None:
+                # Attend to the chunk's K/V as quantized — identical to how
+                # a later chunk / decode step reads it back from the pool.
+                # The compute-dtype round-trip matches
+                # ``kv_quant.fake_quant`` (which returns x.dtype) bitwise.
+                k = fake_quant(k).astype(kn_ref.dtype).astype(jnp.float32)
+                v = fake_quant(v.astype(jnp.float32)).astype(vn_ref.dtype)
+            s = q @ k.T  # (rows, S)
+            online_update(h, jnp.where(visible, s, NEG_INF), v)
 
     # Scatter phase: merge one destination block.  Offset o holds cache
     # position w*bs + o = start + j; compacted index j maps back to padded
-    # source row j (vlm prefix) or j + pad (prompt tokens).  The one-hot
-    # matmul places each valid destination row exactly (0/1 coefficients
-    # in fp32 — bit-exact with the host-side scatter after the cast).
+    # source row j (vlm prefix) or j + pad (prompt tokens) — for each of
+    # the two pieces a CONTIGUOUS run of chunk rows, so the block's new
+    # rows (all heads) are one dynamic leading-dim slice of the chunk's
+    # K/V (an exact copy).  The chunk carries ``bs`` zero rows on each
+    # side, so every slice start clamps into bounds without moving a
+    # valid row.
     @pl.when(i >= t_read)
     def _scatter():
         w = jnp.minimum(start // bs + (i - t_read), T - 1)
-        o = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-        j = w * bs + o - start
-        valid = (j >= 0) & (j < prefix + length)
-        src = jnp.where(j < prefix, j, j + pad)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        oh = ((col == src) & valid).astype(jnp.float32)  # (bs, S)
-        kvd = ko_ref.dtype
-        # The one-hot matmul places each valid destination row EXACTLY
-        # (0/1 fp32 coefficients copy the fp32 view of the bf16 row), so
-        # the quantization below starts from the same fp32 values as the
-        # host-side reference — payload and scales match bitwise.
-        new_kf = oh @ kn_ref[...].astype(jnp.float32)  # (bs, D)
-        new_vf = oh @ vn_ref[...].astype(jnp.float32)
-        if kv_dtype is not None:
-            def quant(xf):  # kv_quant.quantize, op-for-op
-                amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-                scale = jnp.where(amax > 0, amax * (1.0 / qm), 1.0)
-                qv = xf / scale
+        base = w * bs - start  # chunk row of offset 0, before the pad
+
+        def new_rows(ref, j, *head):
+            # The block's new rows: (bs, Hk, D), or (bs, D) for one head.
+            def take(off):
+                return ref[(pl.ds(jnp.clip(off + bs, 0, S + bs), bs),)
+                           + head]
+            new = take(base + pad)
+            if prefix:
+                new = jnp.where(j < prefix, take(base), new)
+            return new.astype(jnp.float32)
+
+        def scale_of(xf):  # kv_quant.quantize's scale, op-for-op
+            amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
+            return jnp.where(amax > 0, amax * (1.0 / qm), 1.0)
+
+        def merge(ref, out_ref, old_ref, sc_out, sc_old):
+            o3 = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+            j3 = w * bs + o3 - start
+            valid3 = (j3 >= 0) & (j3 < prefix + length)
+            new = new_rows(ref, j3)
+            if kv_dtype is not None:
+                # kv_quant.quantize, op-for-op, from the exact fp32 view
+                # of the chunk's rows — payload and scales match the
+                # host-side reference scatter bitwise.  Invalid rows
+                # quantize garbage, but the merge passes the OLD payload
+                # and scale through.
+                new = new / scale_of(new)
                 if kv_dtype == "int8":
-                    qv = jnp.round(qv)
-                return qv.astype(kvd), scale
-            new_k, ksc = quant(new_kf)
-            new_v, vsc = quant(new_vf)
-            # Invalid rows quantize garbage (all-zero -> scale 1), but the
-            # merge passes the OLD payload/scale through bitwise.
-            kso_ref[...] = jnp.where(valid, ksc, ksp_ref[...])
-            vso_ref[...] = jnp.where(valid, vsc, vsp_ref[...])
-        else:
-            new_k = new_kf.astype(kvd)
-            new_v = new_vf.astype(kvd)
-        ko_ref[...] = jnp.where(valid, new_k, kp_ref[...])
-        vo_ref[...] = jnp.where(valid, new_v, vp_ref[...])
+                    new = jnp.round(new)
+                # The (bs, Hk) scale tile, one head column at a time from
+                # the same rows (the same values as the 3D scale above).
+                o = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+                j = w * bs + o - start
+                put = (j >= 0) & (j < prefix + length)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (bs, Hk), 1)
+                sc = sc_old[...]
+                for h in range(Hk):
+                    sc = jnp.where(put & (lane == h),
+                                   scale_of(new_rows(ref, j, h, slice(None))),
+                                   sc)
+                sc_out[...] = sc
+            out_ref[...] = jnp.where(valid3, new.astype(out_ref.dtype),
+                                     old_ref[...])
+
+        merge(kn_ref, ko_ref, kp_ref, kso_ref, ksp_ref)
+        merge(vn_ref, vo_ref, vp_ref, vso_ref, vsp_ref)
 
     @pl.when(i == n_i - 1)
     def _done():
@@ -252,7 +273,7 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
                    SCLAD quantized pool, with static ``kv_dtype``
                    ("int8"/"fp8") naming the payload encoding.  The scales
                    ride the same table-walked BlockSpecs as the payload
-                   (reshaped to (N, bs, Hk, 1) so their tile is 2D) and are
+                   (one (bs, Hk) tile per step) and are
                    aliased in place alongside it; the chunk's new K/V is
                    QUANTIZED IN-KERNEL before the write-back, so compressed
                    bytes are the only thing that round-trips HBM.
@@ -274,45 +295,45 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
 
     qt = q.reshape(B, S, Hk, rep, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, Hk, S * rep, D)
-    knt = k_new.transpose(0, 2, 1, 3)  # (B, Hk, S, D)
-    vnt = v_new.transpose(0, 2, 1, 3)
+    # (B, bs + S + bs, Hk, D): zero rows either side of the chunk (see
+    # the scatter phase).
+    edge = ((0, 0), (bs, bs), (0, 0), (0, 0))
+    knt = jnp.pad(k_new, edge)
+    vnt = jnp.pad(v_new, edge)
 
     # Writes span <= ceil(S/bs)+1 blocks (the +1 absorbs a start%bs
     # straddle); steps clamped past the table end re-merge idempotently.
     t_read = T if has_ctx else 0
     w_steps = min(T, -(-S // bs) + 1)
-    grid = (B, Hk, t_read + w_steps)
+    grid = (B, t_read + w_steps)
 
-    def pool_read_blk(b, h, i, lens, starts, tbl):
+    def pool_read_blk(b, i, lens, starts, tbl):
         wr = jnp.minimum(starts[b] // bs + (i - t_read), T - 1)
         idx = jnp.where(i < t_read, jnp.minimum(i, T - 1), wr)
-        return (tbl[b, idx], 0, h, 0)
+        return tbl[b, idx], 0, 0, 0
 
-    def pool_write_blk(b, h, i, lens, starts, tbl):
+    def pool_write_blk(b, i, lens, starts, tbl):
         # Parked on the FIRST write block during the read phase so the
         # (unwritten) output buffer is never flushed over a context block.
         j = jnp.maximum(i - t_read, 0)
-        return (tbl[b, jnp.minimum(starts[b] // bs + j, T - 1)], 0, h, 0)
+        return tbl[b, jnp.minimum(starts[b] // bs + j, T - 1)], 0, 0, 0
 
-    seq_blk = pl.BlockSpec((None, None, S, D),
-                           lambda b, h, i, lens, starts, tbl: (b, h, 0, 0))
-    pool_rd = pl.BlockSpec((None, bs, None, D), pool_read_blk)
-    pool_wr = pl.BlockSpec((None, bs, None, D), pool_write_blk)
-    # Scales get a trailing singleton ((N, bs, Hk) -> (N, bs, Hk, 1), a
-    # layout-preserving view) so their table-walked tile is 2D (bs, 1).
-    scale_rd = pl.BlockSpec((None, bs, None, 1), pool_read_blk)
-    scale_wr = pl.BlockSpec((None, bs, None, 1), pool_write_blk)
+    def row(b, i, lens, starts, tbl):
+        return b, 0, 0, 0
 
-    in_specs = [
-        pl.BlockSpec((None, None, S * rep, D),
-                     lambda b, h, i, lens, starts, tbl: (b, h, 0, 0)),
-        seq_blk, seq_blk, pool_rd, pool_rd,
-    ]
-    out_specs = [
-        pl.BlockSpec((None, None, S * rep, D),
-                     lambda b, h, i, lens, starts, tbl: (b, h, 0, 0)),
-        pool_wr, pool_wr,
-    ]
+    # A pool block carries every KV head and a scale block its (bs, Hk)
+    # tile: the TPU lowering accepts a block whose last two dims are whole.
+    q_blk = pl.BlockSpec((None, Hk, S * rep, D), row)
+    seq_blk = pl.BlockSpec((None, S + 2 * bs, Hk, D), row)
+    pool_rd = pl.BlockSpec((None, bs, Hk, D), pool_read_blk)
+    pool_wr = pl.BlockSpec((None, bs, Hk, D), pool_write_blk)
+    scale_rd = pl.BlockSpec((None, bs, Hk),
+                            lambda *a: pool_read_blk(*a)[:3])
+    scale_wr = pl.BlockSpec((None, bs, Hk),
+                            lambda *a: pool_write_blk(*a)[:3])
+
+    in_specs = [q_blk, seq_blk, seq_blk, pool_rd, pool_rd]
+    out_specs = [q_blk, pool_wr, pool_wr]
     out_shape = [
         jax.ShapeDtypeStruct((B, Hk, S * rep, D), q.dtype),
         jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
@@ -326,14 +347,12 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
     # every pool update happens in place.
     aliases = {6: 1, 7: 2}
     if quantized:
-        k_scale, v_scale = kv_scales
-        ks4 = k_scale.astype(jnp.float32)[..., None]
-        vs4 = v_scale.astype(jnp.float32)[..., None]
+        k_scale, v_scale = (x.astype(jnp.float32) for x in kv_scales)
         in_specs += [scale_rd, scale_rd]
         out_specs += [scale_wr, scale_wr]
-        out_shape += [jax.ShapeDtypeStruct(ks4.shape, jnp.float32),
-                      jax.ShapeDtypeStruct(vs4.shape, jnp.float32)]
-        inputs += [ks4, vs4]
+        out_shape += [jax.ShapeDtypeStruct(k_scale.shape, jnp.float32),
+                      jax.ShapeDtypeStruct(v_scale.shape, jnp.float32)]
+        inputs += [k_scale, v_scale]
         aliases = {6: 1, 7: 2, 8: 3, 9: 4}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -342,9 +361,9 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((S * rep, D), jnp.float32),  # acc
-            pltpu.VMEM((S * rep, 1), jnp.float32),  # running max
-            pltpu.VMEM((S * rep, 1), jnp.float32),  # running denom
+            pltpu.VMEM((Hk, S * rep, D), jnp.float32),  # acc
+            pltpu.VMEM((Hk, S * rep, 1), jnp.float32),  # running max
+            pltpu.VMEM((Hk, S * rep, 1), jnp.float32),  # running denom
         ],
     )
     results = pl.pallas_call(
@@ -359,7 +378,6 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
     out = results[0].reshape(B, Hk, S, rep, D).transpose(0, 2, 1, 3, 4)
     out = out.reshape(B, S, H * D)
     if quantized:
-        _, k_pool, v_pool, ks4, vs4 = results
-        return out, k_pool, v_pool, ks4[..., 0], vs4[..., 0]
+        return (out,) + tuple(results[1:])
     _, k_pool, v_pool = results
     return out, k_pool, v_pool

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 # Same probe + mode set as the decode-side kernel: "attn_kernel" selects
@@ -123,6 +124,5 @@ def _sharded_paged_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
                                  kv_scales=tuple(rest) or None,
                                  kv_dtype=kv_dtype)
 
-    return sharding.shard_map(body, mesh, in_specs=tuple(in_specs),
-                              out_specs=tuple(out_specs),
-                              check_vma=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=tuple(out_specs), check_vma=False)(*args)

@@ -48,8 +48,8 @@ def _sclad_kernel(x_ref, vals_ref, rows_ref, o_ref, w_scratch, acc_scratch,
     w_scratch[...] = jnp.zeros_like(w_scratch)
     for c in range(C):  # C is static (uniform N:M block compression)
         r = rows_ref[c]
-        pl.store(w_scratch, (pl.dslice(r * UNIT_R, UNIT_R), slice(None)),
-                 vals_ref[c].astype(w_scratch.dtype))
+        w_scratch[pl.ds(r * UNIT_R, UNIT_R), :] = \
+            vals_ref[c].astype(w_scratch.dtype)
 
     # Dense MXU matmul on the decoded tile — compute is sparsity-agnostic.
     x = x_ref[...]

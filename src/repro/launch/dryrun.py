@@ -43,7 +43,6 @@ import jax
 from repro.configs.base import SHAPES, get_config, list_archs
 from repro.core import roofline
 from repro.launch import mesh as mesh_lib, steps as steps_lib
-from repro.parallel import sharding
 from repro.models import model as M
 from repro.models import layers as layers_lib
 
@@ -168,14 +167,11 @@ def _measure(cfg, shape_name: str, mesh, want_memory: bool):
     # Decode: donate the KV/state cache so XLA aliases it in place instead
     # of copying the full multi-GB cache every token.
     donate = (1,) if SHAPES[shape_name].is_decode else ()
-    with sharding.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                           donate_argnums=donate).lower(*args)
         compiled = lowered.compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # jax < 0.5 returns [dict] per device
-            cost = cost[0] if cost else {}
-        cost = cost or {}
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         mem = compiled.memory_analysis() if want_memory else None
     n_dev = mesh.devices.size

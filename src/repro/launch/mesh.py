@@ -7,9 +7,11 @@ jax; everything else sees the real device count.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,3 +38,17 @@ def data_axes(mesh) -> Tuple[str, ...]:
     """The batch-sharding axes for this mesh ('pod' included when present)."""
     names = mesh.axis_names
     return tuple(a for a in ("pod", "data") if a in names)
+
+
+def engine_mesh(devices: Sequence) -> Mesh:
+    """The (1, len(devices)) ("data", "model") mesh of ONE serving engine:
+    several devices shard its weights and KV heads over ``model`` (tensor
+    parallelism); one device pins the engine, params and pool, there."""
+    return Mesh(np.array(devices).reshape(1, -1), ("data", "model"))
+
+
+def replica_meshes(n: int) -> List[Mesh]:
+    """One single-device engine mesh per serving replica, dealt over the
+    devices in order: replica i lives on device i mod device count."""
+    devs = jax.devices()
+    return [engine_mesh([devs[i % len(devs)]]) for i in range(n)]

@@ -30,6 +30,8 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import replica_meshes
 from repro.models import kv_quant
 from repro.models import model as M
 from repro.serving.engine import ServingEngine
@@ -222,14 +224,15 @@ def main():
                                  f"--replicas {args.replicas}")
     if args.fault_seed is not None and args.frontend != "async":
         raise SystemExit("--fault-seed requires --frontend async")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
 
-    def make_engine():
+    def make_engine(mesh=None):
         return ServingEngine(
-            cfg, params, max_batch=args.max_batch,
+            cfg, params, max_batch=args.max_batch, mesh=mesh,
             max_len=64 + args.shared_prefix + args.max_new, mode=args.mode,
             seed=args.seed,
             block_size=args.block_size, num_blocks=args.num_blocks,
@@ -241,7 +244,11 @@ def main():
             spec_decode=args.spec_decode, spec_k=args.spec_k,
             sampler=SamplerConfig(temperature=args.temperature, top_k=50))
 
-    engine = make_engine()
+    # Replicas each on their own device (round-robin when there are fewer
+    # devices than replicas); a single engine keeps the default device.
+    engines = [make_engine(m) for m in replica_meshes(args.replicas)] \
+        if args.replicas > 1 else [make_engine()]
+    engine = engines[0]
     rng = np.random.default_rng(args.seed)
     system = rng.integers(1, cfg.vocab_size, size=args.shared_prefix)
 
@@ -258,8 +265,6 @@ def main():
         # measures serving latency, not compilation — the SAME
         # (group-size, chunk-bucket) coverage rule the bench uses,
         # derived from the actual trace (see serving.warmup).
-        engines = [engine] + [make_engine()
-                              for _ in range(args.replicas - 1)]
         lens = trace_prompt_lens(trace, engine,
                                  extra=(16 + args.shared_prefix,))
         for e in engines:

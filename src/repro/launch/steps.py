@@ -58,7 +58,7 @@ def shardings_for(cfg: ModelConfig, shape_name: str, mesh):
 
     Axis state is scoped to this call (``sharding.use_axes``), not set
     process-globally: callers that later trace the step (e.g. dryrun's
-    ``jit(...).lower``) do so under ``sharding.mesh_context(mesh)``, which
+    ``jit(...).lower``) do so under ``jax.set_mesh(mesh)``, which
     the constrain_* anchors fall back to."""
     from repro.launch import specs as specs_lib
 

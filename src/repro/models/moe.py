@@ -235,14 +235,14 @@ def manual_path_available(cfg: ModelConfig, T: int) -> bool:
 
 def apply_moe_manual(cfg: ModelConfig, p: Params, x: jnp.ndarray):
     """x: (B, S, d) -> (out, aux). Requires manual_path_available()."""
-    from repro.parallel import sharding as _sh_compat
+    from repro.parallel import sharding as _sh
 
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
     E = m.num_experts
     ep, ep_n, tp, tp_n = _manual_axes()
-    mesh = _sh_compat.current_mesh()
+    mesh = _sh.current_mesh()
     T_loc = T // ep_n
     C = capacity(cfg, T_loc)
     E_loc = E // ep_n
@@ -320,7 +320,7 @@ def apply_moe_manual(cfg: ModelConfig, p: Params, x: jnp.ndarray):
         return y, aux
 
     P_ = _P
-    fn = _sh_compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_(ep, tp), P_(None, None),
                   P_(ep, tp, None), P_(ep, tp, None), P_(ep, None, tp)),

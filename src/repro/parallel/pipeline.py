@@ -38,14 +38,9 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         mb_shape = mbs.shape[1:]
         # The carry becomes device-varying after ppermute; mark the initial
         # values as varying over the stage axis to satisfy shard_map typing.
-        def _vary(x):
-            try:
-                return jax.lax.pvary(x, (axis,))
-            except AttributeError:  # older jax
-                return x
-
-        carry = _vary(jnp.zeros(mb_shape, mbs.dtype))
-        out = _vary(jnp.zeros_like(mbs))
+        carry, out = jax.lax.pcast(
+            (jnp.zeros(mb_shape, mbs.dtype), jnp.zeros_like(mbs)), (axis,),
+            to="varying")
 
         def tick(t, state):
             carry, out = state
@@ -72,8 +67,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         # Every stage holds zeros except the last: reduce to broadcast.
         return jax.lax.psum(out, axis)
 
-    from repro.parallel import sharding as _sh
-    fn = _sh.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P())

@@ -45,8 +45,8 @@ from repro.configs.base import ModelConfig, ShapeSpec
 #   * ``set_mesh_axis_sizes(mesh)`` sets it for the current context
 #     (scripts / tests that want ambient state);
 #   * when nothing was set explicitly, readers fall back to the mesh active
-#     in the enclosing jax context (``jax.set_mesh`` / ``with mesh:``), so
-#     ``jit(...).lower()`` under ``mesh_context`` sees the right axes without
+#     in the enclosing jax context (``jax.set_mesh``), so
+#     ``jit(...).lower()`` under ``jax.set_mesh`` sees the right axes without
 #     any global hand-off.
 # ---------------------------------------------------------------------------
 
@@ -385,47 +385,18 @@ def paged_attn_specs(axis: str = TP_AXIS) -> Dict[str, P]:
     }
 
 
-# ---------------------------------------------------------------------------
-# jax version compatibility (shard_map moved out of experimental in ~0.6;
-# the replication check was renamed check_rep -> check_vma, and the active
-# mesh accessor became jax.sharding.get_abstract_mesh)
-# ---------------------------------------------------------------------------
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def current_mesh():
-    """The mesh active in the enclosing context (``jax.set_mesh`` /
-    ``with mesh:``), or None."""
-    try:
-        from jax.sharding import get_abstract_mesh
-        m = get_abstract_mesh()
-        return None if m is None or not m.axis_names else m
-    except ImportError:  # jax < 0.5: the `with mesh:` thread resource
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh`` (jax.set_mesh on new jax, the
-    Mesh context manager on old)."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    """The mesh active in the enclosing context (``jax.set_mesh``), or
+    None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x, spec: P):
     """with_sharding_constraint that is a no-op outside a mesh context."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError, KeyError, TypeError):
+    if current_mesh() is None:
         return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 SEQUENCE_PARALLEL = True

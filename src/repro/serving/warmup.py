@@ -15,7 +15,7 @@ already compiled when the clock starts.
 """
 from __future__ import annotations
 
-from typing import Iterable, Sequence, Tuple
+from typing import Iterable, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -58,13 +58,16 @@ def trace_prompt_lens(trace: Sequence, engine,
 def warmup_prefill(engine, vocab_size: int,
                    prompt_lens: Iterable[int] = (12,),
                    max_new_tokens: int = 2, seed: int = 99,
-                   reset_stats: bool = True) -> None:
+                   reset_stats: bool = True,
+                   group_sizes: Optional[Iterable[int]] = None) -> None:
     """Warm ``engine``'s jit caches for every admission group size.
 
     For each prompt length in ``prompt_lens`` (deduplicated; pick one
     representative per chunk bucket the real trace can hit, including any
-    shared-prefix length) and each group size ``1..engine.max_batch``,
-    submit that many uniform random prompts and drain them closed-loop.
+    shared-prefix length) and each group size (``group_sizes``; default
+    every size ``1..engine.max_batch`` — a caller whose traffic admits in
+    known group sizes names just those), submit that many uniform random
+    prompts and drain them closed-loop.
     Also compiles the decode window (and the speculative verify pass when
     ``spec_decode`` is on — fixed-shape, so one group covers it).
 
@@ -77,7 +80,8 @@ def warmup_prefill(engine, vocab_size: int,
     for n in sorted({int(n) for n in prompt_lens}):
         if not 0 < n < engine.max_len:
             continue
-        for g in range(1, engine.max_batch + 1):
+        for g in sorted(set(group_sizes or
+                            range(1, engine.max_batch + 1))):
             for _ in range(g):
                 engine.submit(rng.integers(1, vocab_size, size=n),
                               max_new_tokens=max_new_tokens)

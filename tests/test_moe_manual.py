@@ -35,7 +35,7 @@ SCRIPT = textwrap.dedent("""
     mesh = mesh_lib.make_mesh((2, 4), ("data", "model"))
     sh.set_mesh_axis_sizes(mesh)
     assert moe_lib.manual_path_available(cfg, 4 * 32)
-    with sh.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(
             lambda p_, x_: moe_lib.apply_moe_manual(cfg, p_, x_))(p, x)
     out = np.asarray(out, np.float32)
