@@ -268,5 +268,6 @@ def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hk, rep, D), q.dtype),
         interpret=interpret,
+        name="paged_flash_decode",
     )(*inputs)
     return out.reshape(B, H, D)

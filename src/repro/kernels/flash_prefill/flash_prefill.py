@@ -374,6 +374,7 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="paged_flash_prefill",
     )(*inputs)
     out = results[0].reshape(B, Hk, S, rep, D).transpose(0, 2, 1, 3, 4)
     out = out.reshape(B, S, H * D)

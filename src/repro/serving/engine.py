@@ -116,6 +116,15 @@ with the serve shardings from ``parallel.sharding`` (mode="serve").  Axis
 state is ENGINE-SCOPED (``sharding.use_axes`` wraps every jitted-function
 body), so several engines with different meshes can coexist in one process
 and nothing leaks into ambient sharding state.
+
+Tracing: each ``step()`` writes ``jax.profiler.TraceAnnotation`` host spans
+— ``serve.step`` around it; inside, ``serve.admit`` per admission attempt
+(``uid=``), ``serve.grow`` for block growth and copy-on-write,
+``serve.prefill.inputs`` / ``serve.prefill.run`` (``rows=``),
+``serve.decode.inputs`` / ``serve.decode.run`` and ``serve.emit``.  With a
+profiler running they land on its host plane, on the device trace's clock;
+without one each costs about a microsecond.  ``EngineStats`` counts the
+matching times (queue wait, admission to first token, step host time).
 """
 from __future__ import annotations
 
@@ -130,6 +139,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.kernels.flash_prefill.ops import ATTN_KERNEL_MODES
@@ -254,6 +264,33 @@ class EngineStats:
     spec_passes: int = 0
     spec_proposed: int = 0
     spec_accepted: int = 0
+    # Where a request's time goes before its first token, and the host's
+    # share of a step; each sum has its count:
+    #   handoff_s / submitted — the caller's arrival stamp
+    #     (``submit(arrived_t=)``, the async front door's acceptance) to
+    #     engine submit, over stamped submits only;
+    #   queue_wait_s / admissions — engine queue to a lane (a preempted
+    #     request waits again from its re-queue);
+    #   admit_to_first_s / first_tokens — first admission to first token,
+    #     so with no preemption queue wait plus this is the TTFT sum;
+    #   prefill_left_out / prefill_member_ticks — per prefill step, the
+    #     requests mid-prompt whose chunk did not run (they are in the
+    #     other first/continuation cohort), over the requests mid-prompt;
+    #   step_s / engine_steps — wall time of ``step()``, host work and
+    #     the prefill/decode intervals above included;
+    #   between_steps_s / between_steps — end of a step that left work
+    #     pending to the start of the next: the caller's own overhead.
+    submitted: int = 0
+    handoff_s: float = 0.0
+    queue_wait_s: float = 0.0
+    admit_to_first_s: float = 0.0
+    first_tokens: int = 0
+    prefill_left_out: int = 0
+    prefill_member_ticks: int = 0
+    step_s: float = 0.0
+    engine_steps: int = 0
+    between_steps_s: float = 0.0
+    between_steps: int = 0
 
     @property
     def tokens_per_s(self) -> float:
@@ -492,6 +529,13 @@ class ServingEngine:
         self._instant: List[Tuple[int, List[int]]] = []  # zero-budget retires
         #: uid -> submit wall time, consumed when its first token lands.
         self._submit_t: Dict[int, float] = {}
+        #: uid -> time it entered the queue (submit or preemption
+        #: re-queue), consumed at admission.
+        self._queued_t: Dict[int, float] = {}
+        #: uid -> time of its first admission, consumed at its first token.
+        self._admit_t: Dict[int, float] = {}
+        #: End of the last step() if it left work pending, else None.
+        self._busy_end_t: Optional[float] = None
         #: uid -> host time of the request's latest observed token (feeds
         #: the inter-token-latency history).
         self._last_obs_t: Dict[int, float] = {}
@@ -565,12 +609,15 @@ class ServingEngine:
     # -- public API ----------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 32,
                deadline: Optional[float] = None,
-               patch_embeds: Optional[np.ndarray] = None) -> int:
+               patch_embeds: Optional[np.ndarray] = None,
+               arrived_t: Optional[float] = None) -> int:
         """Queue a request.  ``deadline`` feeds preempt_policy="deadline";
         ``patch_embeds`` (vlm only, (num_patches, d_model)) is the
         request's image frontend — its digest seeds the prefix-cache hash
         chain, so only requests with the SAME image (or both the zero
-        stub) can share prefix blocks."""
+        stub) can share prefix blocks.  ``arrived_t`` is the
+        ``time.perf_counter()`` at which a front door accepted the
+        request; the wait until this call adds to ``stats.handoff_s``."""
         if self.poisoned:
             raise RuntimeError(
                 "engine is poisoned: an earlier step() failure left the "
@@ -595,12 +642,7 @@ class ServingEngine:
                     f"patch_embeds shape {patch_embeds.shape} != {want}")
         self._uid += 1
         uid = self._uid
-        if max_new_tokens < 1:
-            # A zero-budget request retires immediately with an empty
-            # output: it never touches the scheduler or the block pool.
-            self._instant.append((uid, []))
-            return uid
-        if self.mode == "continuous":
+        if self.mode == "continuous" and max_new_tokens >= 1:
             worst = self._worst_case_tokens(prompt, max_new_tokens)
             need = self._alloc.blocks_for(worst)
             cap = min(self._alloc.num_blocks, self._alloc.max_blocks_per_slot)
@@ -609,7 +651,18 @@ class ServingEngine:
                     f"request needs {need} KV blocks but the pool/block "
                     f"table caps at {cap}; it can never be admitted "
                     f"(raise num_blocks or shorten the prompt/budget)")
-        self._submit_t[uid] = time.perf_counter()
+        now = time.perf_counter()
+        if arrived_t is not None:
+            self.stats.submitted += 1
+            self.stats.handoff_s += now - arrived_t
+        if max_new_tokens < 1:
+            # A zero-budget request retires immediately with an empty
+            # output: it never touches the scheduler or the block pool.
+            self._instant.append((uid, []))
+            return uid
+        self._submit_t[uid] = now
+        if self.mode == "continuous":
+            self._queued_t[uid] = now
         self._queue.append(Request(
             uid, prompt, max_new_tokens, deadline=deadline,
             patch_embeds=patch_embeds,
@@ -638,6 +691,10 @@ class ServingEngine:
                 self.stats.ttft_s_sum += now - t0
                 self.stats.ttft_count += 1
                 self.stats.ttft_history.append(now - t0)
+            ta = self._admit_t.pop(uid, None)
+            if ta is not None:
+                self.stats.admit_to_first_s += now - ta
+                self.stats.first_tokens += 1
             # Any further tokens in this first window left the same host
             # sync as the first token: there is no measurable gap, so
             # they contribute no ITL samples (they are part of TTFT).
@@ -690,14 +747,26 @@ class ServingEngine:
             raise RuntimeError(
                 "engine is poisoned: an earlier step() failure left the "
                 "block store inconsistent; build a fresh engine")
+        t0 = time.perf_counter()
+        if self._busy_end_t is not None:
+            self.stats.between_steps_s += t0 - self._busy_end_t
+            self.stats.between_steps += 1
         try:
-            return self._step()
+            with TraceAnnotation("serve.step"):
+                return self._step()
         except Exception:
             try:
                 self._alloc.check_invariants()
             except Exception:
                 self.poisoned = True
             raise
+        finally:
+            end = time.perf_counter()
+            self.stats.step_s += end - t0
+            self.stats.engine_steps += 1
+            # Time until the next step counts as the caller's overhead
+            # only while work is waiting; an empty engine waits on load.
+            self._busy_end_t = end if self.has_pending_work() else None
 
     def _step(self) -> List[Tuple[int, List[int]]]:
         finished: List[Tuple[int, List[int]]] = list(self._instant)
@@ -714,29 +783,41 @@ class ServingEngine:
         # tokens land in.  Growth is optimistic: OutOfBlocks preempts the
         # youngest request and retries — possibly preempting the growing
         # lane itself.
-        for i in np.nonzero(self._host_active)[0]:
-            i = int(i)
-            if not self._host_active[i]:
-                continue  # preempted while an earlier lane grew
-            steps_i = min(K, int(self._host_rem[i]))
-            lo = self._prefix + int(self._host_pos[i])
-            self._grow_for_writes(
-                i, lo, lo + steps_i,
-                alive=lambda i=i: bool(self._host_active[i]))
+        with TraceAnnotation("serve.grow"):
+            for i in np.nonzero(self._host_active)[0]:
+                i = int(i)
+                if not self._host_active[i]:
+                    continue  # preempted while an earlier lane grew
+                steps_i = min(K, int(self._host_rem[i]))
+                lo = self._prefix + int(self._host_pos[i])
+                self._grow_for_writes(
+                    i, lo, lo + steps_i,
+                    alive=lambda i=i: bool(self._host_active[i]))
         if not self._host_active.any():
             return finished
         self._note_peak()
-        tables = jnp.asarray(self._alloc.block_table())
+        with TraceAnnotation("serve.decode.inputs"):
+            tables = jnp.asarray(self._alloc.block_table())
 
-        t0 = time.perf_counter()
-        (self._cache, self._logits, self._pos, self._active, self._budget,
-         host_out) = self._decode_fn(
-            self.params, self._cache, self._logits, self._pos, self._active,
-            self._budget, self._keys, tables)
-        host = np.asarray(host_out)  # (2, K, B): the per-window host sync
-        tok_h, active_h = host[0], host[1].astype(bool)
-        self.stats.decode_s += time.perf_counter() - t0
+        with TraceAnnotation("serve.decode.run"):
+            t0 = time.perf_counter()
+            (self._cache, self._logits, self._pos, self._active,
+             self._budget, host_out) = self._decode_fn(
+                self.params, self._cache, self._logits, self._pos,
+                self._active, self._budget, self._keys, tables)
+            host = np.asarray(host_out)  # (2, K, B): the per-window sync
+            tok_h, active_h = host[0], host[1].astype(bool)
+            self.stats.decode_s += time.perf_counter() - t0
+        with TraceAnnotation("serve.emit"):
+            self._emit_window(finished, tok_h, active_h)
+        return finished
 
+    def _emit_window(self, finished: List[Tuple[int, List[int]]],
+                     tok_h: np.ndarray, active_h: np.ndarray) -> None:
+        """Hand a decode window's tokens (``tok_h``/``active_h``, (K, B))
+        to their requests: stream, record latency, commit full blocks and
+        retire finished lanes into ``finished``."""
+        K = self.decode_steps
         was = self._host_active.copy()
         self.stats.peak_decode_lanes = max(self.stats.peak_decode_lanes,
                                            int(was.sum()))
@@ -785,7 +866,6 @@ class ServingEngine:
                 # References drop; exclusive full blocks retire into the
                 # LRU pool (still matchable), partial ones go blank.
                 self._alloc.release(i)
-        return finished
 
     def _spec_step(self, finished: List[Tuple[int, List[int]]]
                    ) -> List[Tuple[int, List[int]]]:
@@ -816,61 +896,23 @@ class ServingEngine:
         plain decode's, which is what makes the bit-identity contract
         compositional across passes.
         """
-        B = self.max_batch
         t0 = time.perf_counter()
-        live = [int(i) for i in np.nonzero(self._host_active)[0]]
-        # Anchors are popped (not read): a lane that doesn't survive to
-        # the end of this pass re-derives its anchor from replayed logits
-        # next time, so a stale cache entry can never outlive its request.
-        cached = {i: self._spec_next.pop(i) for i in list(self._spec_next)}
-        if any(i not in cached for i in live):
-            anchors = np.asarray(self._spec_anchor_fn(
-                self._logits, self._keys,
-                jnp.asarray(self._host_pos, jnp.int32),
-                jnp.asarray(self._host_active)))
-        chunks: Dict[int, List[int]] = {}
-        for i in live:
-            r = self._slot_req[i]
-            chunk = [cached[i] if i in cached else int(anchors[i])]
-            rem_after = int(self._host_rem[i]) - 1
-            if chunk[0] != self.eos_id and rem_after > 0:
-                k = min(self.spec_k, rem_after)
-                hist = [int(t) for t in r.prompt] + r.output + chunk
-                chunk += [int(d) for d in
-                          self._proposer.propose(hist, k)[:k]]
-            chunks[i] = chunk
-        for i in chunks:
-            if not self._host_active[i]:
-                continue  # preempted while an earlier lane grew
-            lo = self._prefix + int(self._host_pos[i])
-            self._grow_for_writes(
-                i, lo, lo + len(chunks[i]),
-                alive=lambda i=i: bool(self._host_active[i]))
-        if not self._host_active.any():
+        with TraceAnnotation("serve.decode.run"):
+            passed = self._spec_verify_pass()
             self.stats.decode_s += time.perf_counter() - t0
+        if passed is None:
             return finished
-        self._note_peak()
+        with TraceAnnotation("serve.emit"):
+            self._emit_spec(finished, *passed)
+        return finished
 
-        P = self.spec_k + 1
-        tokens = np.full((B, P), self.pad_id, np.int32)
-        lengths = np.zeros(B, np.int32)
-        starts = np.zeros(B, np.int32)
-        for i, chunk in chunks.items():
-            if not self._host_active[i]:
-                continue  # its anchor is discarded; recompute replays it
-            lengths[i] = len(chunk)
-            tokens[i, P - len(chunk):] = chunk
-            starts[i] = self._prefix + int(self._host_pos[i])
-            self.stats.spec_proposed += len(chunk) - 1
-        tables = jnp.asarray(self._alloc.block_table())
-        self._cache, self._logits, v_dev, anext_dev = self._spec_verify_fn(
-            self.params, self._cache, jnp.asarray(tokens),
-            jnp.asarray(lengths), tables, jnp.asarray(starts),
-            self._keys, self._logits)
-        v = np.asarray(v_dev)
-        anext = np.asarray(anext_dev)
-        self.stats.decode_s += time.perf_counter() - t0
-
+    def _emit_spec(self, finished: List[Tuple[int, List[int]]],
+                   chunks: Dict[int, List[int]], lengths: np.ndarray,
+                   v: np.ndarray, anext: np.ndarray) -> None:
+        """Step 5 of ``_spec_step``: emit each lane's accepted prefix,
+        roll back the rejected tail and retire finished lanes into
+        ``finished``."""
+        B = self.max_batch
         self.stats.peak_decode_lanes = max(self.stats.peak_decode_lanes,
                                            int((lengths > 0).sum()))
         self.stats.spec_passes += 1
@@ -926,7 +968,63 @@ class ServingEngine:
                 # v + 1), so its position is exactly where `anchor_next`
                 # was sampled — carry it as next pass's anchor.
                 self._spec_next[i] = int(anext[i])
-        return finished
+
+    def _spec_verify_pass(self):
+        """Steps 1-4 of ``_spec_step``: anchors, drafts, growth and the
+        verify dispatch with its host sync.  Returns ``(chunks, lengths,
+        accepted drafts, next anchors)``, or None when growth preempted
+        every lane."""
+        B = self.max_batch
+        live = [int(i) for i in np.nonzero(self._host_active)[0]]
+        # Anchors are popped (not read): a lane that doesn't survive to
+        # the end of this pass re-derives its anchor from replayed logits
+        # next time, so a stale cache entry can never outlive its request.
+        cached = {i: self._spec_next.pop(i) for i in list(self._spec_next)}
+        if any(i not in cached for i in live):
+            anchors = np.asarray(self._spec_anchor_fn(
+                self._logits, self._keys,
+                jnp.asarray(self._host_pos, jnp.int32),
+                jnp.asarray(self._host_active)))
+        chunks: Dict[int, List[int]] = {}
+        for i in live:
+            r = self._slot_req[i]
+            chunk = [cached[i] if i in cached else int(anchors[i])]
+            rem_after = int(self._host_rem[i]) - 1
+            if chunk[0] != self.eos_id and rem_after > 0:
+                k = min(self.spec_k, rem_after)
+                hist = [int(t) for t in r.prompt] + r.output + chunk
+                chunk += [int(d) for d in
+                          self._proposer.propose(hist, k)[:k]]
+            chunks[i] = chunk
+        with TraceAnnotation("serve.grow"):
+            for i in chunks:
+                if not self._host_active[i]:
+                    continue  # preempted while an earlier lane grew
+                lo = self._prefix + int(self._host_pos[i])
+                self._grow_for_writes(
+                    i, lo, lo + len(chunks[i]),
+                    alive=lambda i=i: bool(self._host_active[i]))
+        if not self._host_active.any():
+            return None
+        self._note_peak()
+
+        P = self.spec_k + 1
+        tokens = np.full((B, P), self.pad_id, np.int32)
+        lengths = np.zeros(B, np.int32)
+        starts = np.zeros(B, np.int32)
+        for i, chunk in chunks.items():
+            if not self._host_active[i]:
+                continue  # its anchor is discarded; recompute replays it
+            lengths[i] = len(chunk)
+            tokens[i, P - len(chunk):] = chunk
+            starts[i] = self._prefix + int(self._host_pos[i])
+            self.stats.spec_proposed += len(chunk) - 1
+        tables = jnp.asarray(self._alloc.block_table())
+        self._cache, self._logits, v_dev, anext_dev = self._spec_verify_fn(
+            self.params, self._cache, jnp.asarray(tokens),
+            jnp.asarray(lengths), tables, jnp.asarray(starts),
+            self._keys, self._logits)
+        return chunks, lengths, np.asarray(v_dev), np.asarray(anext_dev)
 
     def has_pending_work(self) -> bool:
         """True while any request is queued, prefilling, decoding or
@@ -985,8 +1083,9 @@ class ServingEngine:
         scheduler state to unwind)."""
         if self.mode != "continuous":
             raise RuntimeError("cancel() requires mode='continuous'")
-        self._submit_t.pop(uid, None)
-        self._last_obs_t.pop(uid, None)
+        for d in (self._submit_t, self._queued_t, self._admit_t,
+                  self._last_obs_t):
+            d.pop(uid, None)
         for i, (u, _) in enumerate(self._instant):
             if u == uid:
                 self._instant.pop(i)
@@ -1123,22 +1222,22 @@ class ServingEngine:
         # of retraces small.  vlm first chunks take the cohort's (possibly
         # per-request) patch embeddings explicitly.
         mesh = self._mesh
-        if cfg.family == "vlm":
-            self._prefill_first = jax.jit(
-                self._scoped(
-                    lambda p, c, t, ln, bt, pe: M.prefill_slots(
-                        cfg, p, c, t, ln, bt, patch_embeds=pe, mesh=mesh)),
-                donate_argnums=(1,) if donate else ())
-        else:
-            self._prefill_first = jax.jit(
-                self._scoped(
-                    lambda p, c, t, ln, bt: M.prefill_slots(
-                        cfg, p, c, t, ln, bt, mesh=mesh)),
-                donate_argnums=(1,) if donate else ())
+
+        def prefill_first(params, cache, tokens, lengths, tables,
+                          patch_embeds=None):
+            return M.prefill_slots(cfg, params, cache, tokens, lengths,
+                                   tables, patch_embeds=patch_embeds,
+                                   mesh=mesh)
+
+        def prefill_cont(params, cache, tokens, lengths, tables, starts):
+            return M.prefill_slots(cfg, params, cache, tokens, lengths,
+                                   tables, start=starts, mesh=mesh)
+
+        self._prefill_first = jax.jit(
+            self._scoped(prefill_first),
+            donate_argnums=(1,) if donate else ())
         self._prefill_cont = jax.jit(
-            self._scoped(
-                lambda p, c, t, ln, bt, st: M.prefill_slots(
-                    cfg, p, c, t, ln, bt, start=st, mesh=mesh)),
+            self._scoped(prefill_cont),
             donate_argnums=(1,) if donate else ())
 
         if self._proposer is not None:
@@ -1321,6 +1420,8 @@ class ServingEngine:
             # roll it back so prefix_hit_rate reflects thrash instead of
             # being inflated by it (re-admission re-counts its real hits).
             self.stats.cached_prompt_tokens -= v.counted_cached
+            r = v.req
+        self._queued_t[r.uid] = time.perf_counter()
 
     def _under_pressure(self, alive: Callable[[], bool],
                         op: Callable[[], None]) -> bool:
@@ -1387,33 +1488,46 @@ class ServingEngine:
                 if r is None and i not in owned]
         while self._queue and free:
             r = self._queue[0]
-            eff_len = len(r.prompt) + len(r.output)
-            digests = self._prompt_digests(r) if self.prefix_cache else []
-            cached_blocks, pooled = self._alloc.match_digests(
-                digests,
-                max_cached_tokens=self._prefix + eff_len - 1,
-                min_cached_tokens=self._prefix)
-            need_now = self._alloc.blocks_for(
-                self._prefix + eff_len + 1) - cached_blocks
-            # Matched-but-pooled blocks will be revived out of `available`
-            # by admit, so they cannot double as allocatable headroom.
-            if need_now > self._alloc.available - pooled:
-                break  # FIFO: wait for blocks rather than starve the head
-            lane = free.pop(0)
-            eff = self._effective_prompt(r)
-            cached_len = self._alloc.admit(
-                lane, digests=digests if self.prefix_cache else None,
-                max_cached_tokens=self._prefix + eff_len - 1,
-                min_cached_tokens=self._prefix, seed=r.chain_seed)
-            self._digest_cache.pop(r.uid, None)
-            consumed = max(0, cached_len - self._prefix)
-            self.stats.cached_prompt_tokens += consumed
-            self._prefilling.append(_Prefilling(
-                r, lane, self._remaining_budget(r), eff,
-                consumed=consumed, cached_len=cached_len,
-                counted_cached=consumed))
-            self._queue.pop(0)
-            self.stats.admissions += 1
+            with TraceAnnotation("serve.admit", uid=r.uid):
+                if not self._admit_head(r, free.pop(0)):
+                    break  # FIFO: wait for blocks rather than starve it
+
+    def _admit_head(self, r: Request, lane: int) -> bool:
+        """Admit the queue head ``r`` onto ``lane`` if the store can cover
+        its uncached prompt tail plus one decode block right now."""
+        eff_len = len(r.prompt) + len(r.output)
+        digests = self._prompt_digests(r) if self.prefix_cache else []
+        cached_blocks, pooled = self._alloc.match_digests(
+            digests,
+            max_cached_tokens=self._prefix + eff_len - 1,
+            min_cached_tokens=self._prefix)
+        need_now = self._alloc.blocks_for(
+            self._prefix + eff_len + 1) - cached_blocks
+        # Matched-but-pooled blocks will be revived out of `available`
+        # by admit, so they cannot double as allocatable headroom.
+        if need_now > self._alloc.available - pooled:
+            return False
+        eff = self._effective_prompt(r)
+        cached_len = self._alloc.admit(
+            lane, digests=digests if self.prefix_cache else None,
+            max_cached_tokens=self._prefix + eff_len - 1,
+            min_cached_tokens=self._prefix, seed=r.chain_seed)
+        self._digest_cache.pop(r.uid, None)
+        consumed = max(0, cached_len - self._prefix)
+        self.stats.cached_prompt_tokens += consumed
+        self._prefilling.append(_Prefilling(
+            r, lane, self._remaining_budget(r), eff,
+            consumed=consumed, cached_len=cached_len,
+            counted_cached=consumed))
+        self._queue.pop(0)
+        self.stats.admissions += 1
+        now = time.perf_counter()
+        self.stats.queue_wait_s += now - self._queued_t.pop(r.uid)
+        if r.uid in self._submit_t:
+            # Still waiting for its first token: keep the first
+            # admission's time across preemptions.
+            self._admit_t.setdefault(r.uid, now)
+        return True
 
     def _prefill_step(self) -> None:
         """Run ONE prefill chunk for the current admission cohort."""
@@ -1428,21 +1542,24 @@ class ServingEngine:
 
         first = _first(self._prefilling[0])
         cohort = [s for s in self._prefilling if _first(s) == first]
+        self.stats.prefill_member_ticks += len(self._prefilling)
+        self.stats.prefill_left_out += len(self._prefilling) - len(cohort)
         cap = self.prefill_chunk or self.max_len
 
         # Grow every member's blocks (write-barriered) BEFORE assembling
         # the batch: growth can preempt cohort members (including the one
         # being grown), which drops them from this chunk.
         ready: List[Tuple[_Prefilling, int]] = []
-        for s in cohort:
-            if s not in self._prefilling:
-                continue  # preempted as a victim of an earlier member
-            take = min(cap, len(s.tokens) - s.consumed)
-            lo = self._prefix + s.consumed
-            if self._grow_for_writes(
-                    s.lane, lo, lo + take,
-                    alive=lambda s=s: s in self._prefilling):
-                ready.append((s, take))
+        with TraceAnnotation("serve.grow"):
+            for s in cohort:
+                if s not in self._prefilling:
+                    continue  # preempted as a victim of an earlier member
+                take = min(cap, len(s.tokens) - s.consumed)
+                lo = self._prefix + s.consumed
+                if self._grow_for_writes(
+                        s.lane, lo, lo + take,
+                        alive=lambda s=s: s in self._prefilling):
+                    ready.append((s, take))
         # A LATER member's growth may have preempted an earlier one that
         # had already grown — drop it, or its chunk would be written into
         # released blocks and the preempted request wrongly activated.
@@ -1453,67 +1570,68 @@ class ServingEngine:
         cohort, takes = [s for s, _ in ready], [t for _, t in ready]
         P = _bucket(max(takes), cap)
         n = len(cohort)
-        tokens = np.full((n, P), self.pad_id, np.int32)
-        lengths = np.empty(n, np.int32)
-        starts = np.empty(n, np.int32)
-        for j, (s, take) in enumerate(zip(cohort, takes)):
-            tokens[j, P - take:] = s.tokens[s.consumed:s.consumed + take]
-            lengths[j] = take
-            starts[j] = self._prefix + s.consumed
-        tables = jnp.asarray(
-            self._alloc.block_table()[[s.lane for s in cohort]])
-
-        t0 = time.perf_counter()
-        if first:
-            if self.cfg.family == "vlm":
-                # Per-request images; the zero stub for requests without.
-                pe = np.zeros((n, self.cfg.num_patches, self.cfg.d_model),
-                              np.float32)
-                for j, (s, _) in enumerate(zip(cohort, takes)):
-                    if s.req.patch_embeds is not None:
-                        pe[j] = s.req.patch_embeds
-                logits_new, self._cache = self._prefill_first(
-                    self.params, self._cache, jnp.asarray(tokens),
-                    jnp.asarray(lengths), tables,
-                    jnp.asarray(pe).astype(jnp.bfloat16))
+        with TraceAnnotation("serve.prefill.inputs"):
+            tokens = np.full((n, P), self.pad_id, np.int32)
+            lengths = np.empty(n, np.int32)
+            starts = np.empty(n, np.int32)
+            for j, (s, take) in enumerate(zip(cohort, takes)):
+                tokens[j, P - take:] = s.tokens[s.consumed:s.consumed + take]
+                lengths[j] = take
+                starts[j] = self._prefix + s.consumed
+            tables = jnp.asarray(
+                self._alloc.block_table()[[s.lane for s in cohort]])
+        with TraceAnnotation("serve.prefill.run", rows=n):
+            t0 = time.perf_counter()
+            if first:
+                if self.cfg.family == "vlm":
+                    # Per-request images; the zero stub for requests without.
+                    pe = np.zeros((n, self.cfg.num_patches, self.cfg.d_model),
+                                  np.float32)
+                    for j, (s, _) in enumerate(zip(cohort, takes)):
+                        if s.req.patch_embeds is not None:
+                            pe[j] = s.req.patch_embeds
+                    logits_new, self._cache = self._prefill_first(
+                        self.params, self._cache, jnp.asarray(tokens),
+                        jnp.asarray(lengths), tables,
+                        jnp.asarray(pe).astype(jnp.bfloat16))
+                else:
+                    logits_new, self._cache = self._prefill_first(
+                        self.params, self._cache, jnp.asarray(tokens),
+                        jnp.asarray(lengths), tables)
             else:
-                logits_new, self._cache = self._prefill_first(
+                logits_new, self._cache = self._prefill_cont(
                     self.params, self._cache, jnp.asarray(tokens),
-                    jnp.asarray(lengths), tables)
-        else:
-            logits_new, self._cache = self._prefill_cont(
-                self.params, self._cache, jnp.asarray(tokens),
-                jnp.asarray(lengths), tables, jnp.asarray(starts))
+                    jnp.asarray(lengths), tables, jnp.asarray(starts))
 
-        done_rows, done = [], []
-        for j, (s, take) in enumerate(zip(cohort, takes)):
-            s.consumed += take
-            if self.prefix_cache:
-                self._alloc.commit_full(s.lane, self._content_ids(s.req))
-            if s.consumed == len(s.tokens):
-                done_rows.append(j)
-                done.append(s)
-                self._slot_req[s.lane] = s.req
-                self._prefilling.remove(s)
-        if done:
-            rows = jnp.asarray(done_rows)
-            lanes = jnp.asarray([s.lane for s in done])
-            plens = jnp.asarray([len(s.tokens) for s in done], jnp.int32)
-            budgets = jnp.asarray([s.budget for s in done], jnp.int32)
-            self._logits = self._logits.at[lanes].set(logits_new[rows])
-            self._pos = self._pos.at[lanes].set(plens)
-            self._active = self._active.at[lanes].set(True)
-            self._budget = self._budget.at[lanes].set(budgets)
-            self._keys = self._keys.at[lanes].set(jnp.stack(
-                [self._request_key(s.req) for s in done]))
-            for s in done:
-                self._host_active[s.lane] = True
-                self._host_pos[s.lane] = len(s.tokens)
-                self._host_rem[s.lane] = s.budget
-        jax.block_until_ready(self._logits)
-        self.stats.prefill_s += time.perf_counter() - t0
-        self.stats.prefill_tokens += int(sum(takes))
-        self.stats.prefill_chunks += 1
+            done_rows, done = [], []
+            for j, (s, take) in enumerate(zip(cohort, takes)):
+                s.consumed += take
+                if self.prefix_cache:
+                    self._alloc.commit_full(s.lane, self._content_ids(s.req))
+                if s.consumed == len(s.tokens):
+                    done_rows.append(j)
+                    done.append(s)
+                    self._slot_req[s.lane] = s.req
+                    self._prefilling.remove(s)
+            if done:
+                rows = jnp.asarray(done_rows)
+                lanes = jnp.asarray([s.lane for s in done])
+                plens = jnp.asarray([len(s.tokens) for s in done], jnp.int32)
+                budgets = jnp.asarray([s.budget for s in done], jnp.int32)
+                self._logits = self._logits.at[lanes].set(logits_new[rows])
+                self._pos = self._pos.at[lanes].set(plens)
+                self._active = self._active.at[lanes].set(True)
+                self._budget = self._budget.at[lanes].set(budgets)
+                self._keys = self._keys.at[lanes].set(jnp.stack(
+                    [self._request_key(s.req) for s in done]))
+                for s in done:
+                    self._host_active[s.lane] = True
+                    self._host_pos[s.lane] = len(s.tokens)
+                    self._host_rem[s.lane] = s.budget
+            jax.block_until_ready(self._logits)
+            self.stats.prefill_s += time.perf_counter() - t0
+            self.stats.prefill_tokens += int(sum(takes))
+            self.stats.prefill_chunks += 1
 
     def _request_key(self, r: Request):
         """The request's base PRNG key: fold_in(seed, uid).  The decode

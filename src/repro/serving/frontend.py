@@ -66,9 +66,11 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import annotate_function
 
 from repro.serving.engine import ServingEngine
 
@@ -218,6 +220,9 @@ class _Ticket:
     queue: asyncio.Queue
     probe: bool = False
     uid: Optional[int] = None  # engine uid, assigned by the pump
+    #: ``time.perf_counter()`` at acceptance; the engine counts the wait
+    #: until the pump submits the request as its hand-off time.
+    arrived_t: float = field(default_factory=time.perf_counter)
     cancelled: bool = False
     done: bool = False
     #: The engine's final token list (completed requests only) — must
@@ -539,6 +544,7 @@ class AsyncFrontend:
         ``step()``; events are routed to queues on the event loop."""
         self._events.append(("tok", uid, token))
 
+    @partial(annotate_function, name="serve.tick")
     def _tick(self) -> List[Tuple[int, List[int]]]:
         """ONE serialized engine interaction (pump thread): apply
         cancels, submit pending requests in arrival order, step the
@@ -556,7 +562,8 @@ class AsyncFrontend:
             try:
                 t.uid = eng.submit(
                     t.prompt, max_new_tokens=t.max_new_tokens,
-                    deadline=t.deadline, patch_embeds=t.patch_embeds)
+                    deadline=t.deadline, patch_embeds=t.patch_embeds,
+                    arrived_t=t.arrived_t)
             except Exception as e:  # validation error -> the stream
                 self._events.append(("err", t, e))
                 continue
@@ -586,6 +593,7 @@ class AsyncFrontend:
         }
         return finished
 
+    @partial(annotate_function, name="serve.dispatch")
     def _dispatch(self, finished: List[Tuple[int, List[int]]]) -> None:
         """Route the tick's events to per-request queues (event loop)."""
         events, self._events = self._events, []

@@ -82,6 +82,7 @@ def _bare_engine():
     eng = ServingEngine.__new__(ServingEngine)
     eng.stats = EngineStats()
     eng._submit_t = {}
+    eng._admit_t = {}
     eng._last_obs_t = {}
     return eng
 
