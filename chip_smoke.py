@@ -260,16 +260,18 @@ def compare_logits(got, want, label: str) -> None:
 
 
 def pool_writes(cfg, kv_dtype: str, seed: int = 0):
-    """Elements of one layer's pool whose bits differ between the prefill
-    kernel and the reference scatter, after a first chunk and after a
-    cached-context continuation (row 0 starting mid-block), both paths
-    given the same materialized inputs and the same starting pool.  Block
-    0, the trash block, is garbage by contract and left out."""
+    """Elements of a two-layer stacked pool whose bits differ between the
+    prefill kernel and the reference scatter, both writing layer 1, after
+    a first chunk and after a cached-context continuation (row 0 starting
+    mid-block), both paths given the same materialized inputs and the same
+    starting pool.  Layer 0 must come through untouched on both paths.
+    Block 0, the trash block, is garbage by contract and left out."""
     quant = None if kv_dtype == "bf16" else kv_dtype
-    layer = M.init_paged_cache(replace(cfg, num_layers=1, kv_dtype=kv_dtype),
-                               PROBE_BLOCKS + 1, BLOCK)
-    pool = tuple(layer[k][0] for k in ("k", "v", "k_scale", "v_scale")
-                 if k in layer)
+    stacked = M.init_paged_cache(
+        replace(cfg, num_layers=2, kv_dtype=kv_dtype), PROBE_BLOCKS + 1,
+        BLOCK)
+    pool = tuple(stacked[k] for k in ("k", "v", "k_scale", "v_scale")
+                 if k in stacked)
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     heads, kv_heads, dim = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     tables = probe_tables()
@@ -282,8 +284,8 @@ def pool_writes(cfg, kv_dtype: str, seed: int = 0):
     def write(kernel, pool, qkv, lens, start):
         def fn(pool, q, k, v, lens, start):
             return prefill_attention(
-                q, k, v, pool[0], pool[1], lens, tables, start=start,
-                kernel=kernel, kv_scales=pool[2:] or None,
+                q, k, v, pool[0], pool[1], lens, tables, layer=1,
+                start=start, kernel=kernel, kv_scales=pool[2:] or None,
                 kv_dtype=quant)[1:]
         return jax.jit(fn)(pool, *qkv, np.asarray(lens, np.int32), start)
 
@@ -298,7 +300,7 @@ def pool_writes(cfg, kv_dtype: str, seed: int = 0):
             for kernel in ("on", "off")})):
         for name, x, y in zip(("k", "v", "k_scale", "v_scale"),
                               res["on"], res["off"]):
-            x, y = np.asarray(x)[1:], np.asarray(y)[1:]
+            x, y = np.asarray(x)[:, 1:], np.asarray(y)[:, 1:]
             bits = f"u{x.itemsize}"
             differ[f"{after}.{name}"] = int(np.sum(
                 x.view(bits) != y.view(bits)))
@@ -371,8 +373,8 @@ def run_four_chips(cfg, params, seed: int) -> None:
                                                sum(solo_out, [])))
     sharded = model_steps(cfg, tp.params, mesh=mesh)
     del tp
-    shard_pool = f"[{PROBE_BLOCKS + 1},{BLOCK},{cfg.num_kv_heads // 4}," \
-        f"{cfg.head_dim}]"
+    shard_pool = f"[{cfg.num_layers},{PROBE_BLOCKS + 1},{BLOCK}," \
+        f"{cfg.num_kv_heads * cfg.head_dim // 4}]"
     for name, text in sharded["hlo"].items():
         assert "tpu_custom_call" in text, f"{name}: no Pallas kernel"
         assert shard_pool in text, f"{name}: kernel not on a pool shard"

@@ -9,20 +9,22 @@ CC-MEM contract (PAPER.md §CC-MEM).  Two cache layouts share the math:
     one KV head in VMEM and streams that head's K/V in ``block_k`` tiles
     with online softmax.  ``lengths`` is per-row: rows of a continuous
     batch sit at different sequence offsets.
-  * ``paged_flash_decode`` — the serving engine's block-pool layout
-    (N, bs, Hk, D) addressed through per-lane block tables
-    (``serving.paged.BlockStore``).  The grid is (batch, table_width),
-    one program per pool block with all its kv heads (the TPU lowering
-    needs the block's last two dims, (Hk, D), whole), and the block table
-    rides the scalar-prefetch channel
-    (``PrefetchScalarGridSpec``): the index map resolves ``tables[b, i]``
-    BEFORE the program body runs, so each program's K/V block is DMA'd
-    straight from the shared pool — no dense per-lane copy of the pool is
-    ever materialized (the O(B·T·bs·Hk·D) gather this kernel replaces).
-    The online-softmax accumulator lives in VMEM scratch and persists
-    across the (sequential, innermost) block dimension of the grid; blocks
-    at or beyond a row's length are skipped, and the trash blocks dead
-    lanes' tables point at are naturally masked by ``lengths``.
+  * ``paged_flash_decode`` — the serving engine's block pool: one
+    lane-dense (L, N, bs, Hk*D) buffer for every layer, addressed through
+    per-lane block tables (``serving.paged.BlockStore``) and a layer
+    index.  The grid is (batch, table_width), one program per pool block
+    with all its kv heads (the block's last two dims, (bs, Hk*D), are
+    whole; head h is the lane range [h*D, (h+1)*D)), and the block table
+    and the layer index ride the scalar-prefetch channel
+    (``PrefetchScalarGridSpec``): the index map resolves
+    ``(layer, tables[b, i])`` BEFORE the program body runs, so each
+    program's K/V block is DMA'd straight from the shared stacked pool —
+    no per-lane gather and no per-layer slice of the pool is ever
+    materialized.  The online-softmax accumulator lives in VMEM scratch
+    and persists across the (sequential, innermost) block dimension of
+    the grid; blocks at or beyond a row's length are skipped, and the
+    trash blocks dead lanes' tables point at are naturally masked by
+    ``lengths``.
 """
 from __future__ import annotations
 
@@ -121,20 +123,22 @@ def _head_col(x, h: int):
     return jnp.sum(jnp.where(lane == h, x, 0.0), axis=1, keepdims=True)
 
 
-def _paged_decode_kernel(lens_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
-                         bs: int, block_k: int, sm_scale: float,
+def _paged_decode_kernel(lens_ref, tbl_ref, lyr_ref, q_ref, k_ref, v_ref,
+                         *rest, bs: int, block_k: int, sm_scale: float,
                          quantized: bool):
     """One program = one pool block of one row, all of its KV heads.
 
-    lens_ref (B,) / tbl_ref (B, T): scalar-prefetch SMEM (the table also
-    drives the K/V index maps); q_ref (Hk, rep, D); k_ref/v_ref
-    (bs, Hk, D): THIS grid step's pool block, already resolved through the
-    table; o_ref (Hk, rep, D).  acc/m/l: VMEM scratch carrying each head's
-    online softmax across the T (innermost, sequential) grid dimension.
+    lens_ref (B,) / tbl_ref (B, T) / lyr_ref (1,): scalar-prefetch SMEM
+    (the table and the layer index also drive the K/V index maps); q_ref
+    (Hk, rep, D); k_ref/v_ref (bs, Hk*D): THIS grid step's pool block,
+    already resolved through (layer, table); o_ref (Hk, rep, D).
+    acc/m/l: VMEM scratch carrying each head's online softmax across the
+    T (innermost, sequential) grid dimension.
 
-    The block holds every KV head: the TPU lowering needs a block's last
-    two dims to be (8, 128)-aligned or whole, and (Hk, D) is whole where
-    a single-head (1, D) slab is not.  Heads are a static loop in VMEM.
+    The block is lane-dense: head h is the lane range [h*D, (h+1)*D) of
+    the block's (bs, Hk*D) tile, whose two dims are whole (the TPU
+    lowering needs a block's last two dims (8, 128)-aligned or whole).
+    Heads are a static loop in VMEM.
 
     ``quantized`` (SCLAD pool): two extra (bs, Hk) fp32 refs ks/vs carry the
     block's per-position-per-head scales (resolved through the SAME table
@@ -149,7 +153,7 @@ def _paged_decode_kernel(lens_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, acc_ref, m_ref, l_ref = rest
     b, i = pl.program_id(0), pl.program_id(1)
     T = pl.num_programs(1)
-    Hk = k_ref.shape[1]
+    Hk, D = q_ref.shape[0], q_ref.shape[2]
     length = lens_ref[b]
 
     @pl.when(i == 0)
@@ -165,8 +169,8 @@ def _paged_decode_kernel(lens_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
         for h in range(Hk):
             q = q_ref[h].astype(jnp.float32) * sm_scale  # (rep, D)
             for s0 in range(0, bs, block_k):  # static sub-tiling
-                k = k_ref[s0:s0 + block_k, h, :]
-                v = v_ref[s0:s0 + block_k, h, :]
+                k = k_ref[s0:s0 + block_k, h * D:(h + 1) * D]
+                v = v_ref[s0:s0 + block_k, h * D:(h + 1) * D]
                 if quantized:
                     # Load-as-Dense: (bs', D) payload * (bs', 1) scale in
                     # fp32, then ROUNDED to the compute dtype — the exact
@@ -197,14 +201,15 @@ def _paged_decode_kernel(lens_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, *,
+def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, layer=0, *,
                        block_k: int = 0, interpret: bool = False,
                        kv_scales=None):
     """Decode attention straight out of the paged KV block pool.
 
     q:            (B, H, D) — one new token per row;
-    k_pool/v_pool:(N, bs, Hk, D) — the SHARED block pool
-                  (``model.init_paged_cache`` layout, trash block included);
+    k_pool/v_pool:(L, N, bs, Hk*D) — the SHARED lane-dense block pool of
+                  every layer (``model.init_paged_cache`` layout, trash
+                  block included);
     lengths:      (B,) int32 — valid cache positions per row (dead lanes'
                   lengths only cover trash blocks, so their output is
                   garbage that the caller's active mask discards);
@@ -212,21 +217,25 @@ def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, *,
                   ``j`` to the pool block holding positions
                   [j*bs, (j+1)*bs); unallocated entries point at the trash
                   block and are masked by ``lengths``.
+    layer:        int32 scalar — the layer whose slab of the pool is read
+                  (scalar-prefetched into the index maps: the pool is
+                  never sliced per layer).
     block_k:      inner tile over a block's token dim (<= bs; 0 => whole
                   block per step).  Rounded down to a divisor of ``bs`` so
                   a caller tuned for the dense kernel's 128 can pass the
                   same value against any pool block size.
-    kv_scales:    optional (k_scale, v_scale) (N, bs, Hk) fp32 — the SCLAD
-                  quantized pool's per-position-per-head scales.  They ride
-                  the same table-walk BlockSpecs as the payload (one
-                  (bs, Hk) scale tile per program) and the dequant multiply
-                  is fused into the block-streaming loop in VMEM.
+    kv_scales:    optional (k_scale, v_scale) (L, N, bs, Hk) fp32 — the
+                  SCLAD quantized pool's per-position-per-head scales.
+                  They ride the same table-walk BlockSpecs as the payload
+                  (one (bs, Hk) scale tile per program) and the dequant
+                  multiply is fused into the block-streaming loop in VMEM.
 
     Returns (B, H, D).  KV bytes are read exactly once per token, block by
     block through the table — never gathered into a per-lane dense copy.
     """
     B, H, D = q.shape
-    bs, Hk = k_pool.shape[1], k_pool.shape[2]
+    bs = k_pool.shape[2]
+    Hk = k_pool.shape[3] // D
     T = block_tables.shape[1]
     rep = H // Hk
     bk = bs if block_k <= 0 else min(block_k, bs)
@@ -236,23 +245,26 @@ def paged_flash_decode(q, k_pool, v_pool, lengths, block_tables, *,
     qt = q.reshape(B, Hk, rep, D)
     quantized = kv_scales is not None
 
-    # The pool is indexed THROUGH the prefetched table: each grid step
-    # DMAs exactly one shared block (all kv heads) of one row.
-    pool_blk = pl.BlockSpec((None, bs, Hk, D),
-                            lambda b, i, lens, tbl: (tbl[b, i], 0, 0, 0))
+    # The pool is indexed THROUGH the prefetched layer and table: each
+    # grid step DMAs exactly one shared block (all kv heads) of one row.
+    def pool_idx(b, i, lens, tbl, lyr):
+        return lyr[0], tbl[b, i], 0, 0
+
+    pool_blk = pl.BlockSpec((None, None, bs, Hk * D), pool_idx)
     row_blk = pl.BlockSpec((None, Hk, rep, D),
-                           lambda b, i, lens, tbl: (b, 0, 0, 0))
+                           lambda b, i, lens, tbl, lyr: (b, 0, 0, 0))
     in_specs = [row_blk, pool_blk, pool_blk]
     inputs = [jnp.asarray(lengths, jnp.int32),
-              jnp.asarray(block_tables, jnp.int32), qt, k_pool, v_pool]
+              jnp.asarray(block_tables, jnp.int32),
+              jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+              qt, k_pool, v_pool]
     if quantized:
-        scale_blk = pl.BlockSpec((None, bs, Hk),
-                                 lambda b, i, lens, tbl: (tbl[b, i], 0, 0))
+        scale_blk = pl.BlockSpec((None, None, bs, Hk), pool_idx)
         in_specs += [scale_blk, scale_blk]
         inputs += [s.astype(jnp.float32) for s in kv_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # lengths, block_tables
+        num_scalar_prefetch=3,  # lengths, block_tables, layer
         grid=(B, T),
         in_specs=in_specs,
         out_specs=row_blk,

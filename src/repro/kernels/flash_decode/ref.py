@@ -39,29 +39,30 @@ def decode_ref(q, k_cache, v_cache, lengths):
 
 
 def paged_decode_ref(q, k_pool, v_pool, lengths, block_tables,
-                     kv_scales=None):
+                     kv_scales=None, layer=0):
     """Gather oracle for the paged kernel: resolve each lane's block table
     into a dense per-lane cache copy, then run ``decode_ref``.
 
-    q: (B, H, D); pools: (N, bs, Hk, D); lengths: (B,) int32;
-    block_tables: (B, T) int32.  This MATERIALIZES the (B, T*bs, Hk, D)
-    copy the kernel exists to avoid — it is the correctness oracle (and the
+    q: (B, H, D); pools: (L, N, bs, Hk*D) lane-dense, every layer;
+    lengths: (B,) int32; block_tables: (B, T) int32; layer: int32 scalar,
+    the layer read.  This MATERIALIZES the (B, T*bs, Hk, D) copy the kernel
+    exists to avoid — it is the correctness oracle (and the
     ``attn_kernel="off"`` fallback), not the hot path.
 
-    kv_scales: (k_scale, v_scale) (N, bs, Hk) fp32 for a SCLAD quantized
+    kv_scales: (k_scale, v_scale) (L, N, bs, Hk) fp32 for a SCLAD quantized
     pool — the gathered payload is dequantized (fp32 multiply, one cast to
     q.dtype: ``models.kv_quant.dequantize``) before attention, the
     load-as-dense half of the compressed layout.
     """
-    B = q.shape[0]
-    Hk, D = k_pool.shape[2], k_pool.shape[3]
-    kc = k_pool[block_tables].reshape(B, -1, Hk, D)
-    vc = v_pool[block_tables].reshape(B, -1, Hk, D)
+    B, _, D = q.shape
+    Hk = k_pool.shape[-1] // D
+    kc = k_pool[layer, block_tables].reshape(B, -1, Hk, D)
+    vc = v_pool[layer, block_tables].reshape(B, -1, Hk, D)
     if kv_scales is not None:
         from repro.models import kv_quant
         k_scale, v_scale = kv_scales
-        ks = k_scale[block_tables].reshape(B, -1, Hk)
-        vs = v_scale[block_tables].reshape(B, -1, Hk)
+        ks = k_scale[layer, block_tables].reshape(B, -1, Hk)
+        vs = v_scale[layer, block_tables].reshape(B, -1, Hk)
         kc = kv_quant.dequantize(kc, ks, q.dtype)
         vc = kv_quant.dequantize(vc, vs, q.dtype)
     return decode_ref(q, kc, vc, lengths)

@@ -13,10 +13,12 @@ HBM again as a separate ``.at[].set`` scatter.  Here instead:
   * the grid is (batch, T_read + W), one program per step taking every
     kv head of its block: the first ``T_read`` steps
     walk the lane's block table on the scalar-prefetch channel
-    (``PrefetchScalarGridSpec`` — the index map resolves ``tbl[b, i]``
-    BEFORE the body runs), streaming cached context K/V block by block
-    straight out of the shared (N, bs, Hk, D) pool with online softmax in
-    VMEM scratch (CC-MEM: each cached KV byte crosses HBM exactly once);
+    (``PrefetchScalarGridSpec`` — the index map resolves
+    ``(layer, tbl[b, i])`` BEFORE the body runs), streaming cached context
+    K/V block by block straight out of the shared lane-dense
+    (L, N, bs, Hk*D) pool of every layer with online softmax in VMEM
+    scratch (CC-MEM: each cached KV byte crosses HBM exactly once; the
+    pool is never sliced per layer);
   * the causal/left-pad mask is derived INSIDE the kernel from the
     ``start``/``lengths`` scalars and the static ``prefix`` — no dense
     (B, S, S) mask is ever built;
@@ -24,7 +26,8 @@ HBM again as a separate ``.at[].set`` scatter.  Here instead:
     K, masked causally with pad keys dropped), fusing what used to be the
     concatenated tail of the dense mask;
   * the last ``W`` steps SCATTER the chunk's new-token K/V into the pool
-    through the table (``input_output_aliases`` pins the pool in place):
+    through the table (``input_output_aliases`` pins the whole stacked
+    pool in place; only the layer's written blocks change):
     each step merges one destination block — old rows kept, new rows
     one contiguous slice of the chunk that folds the left-pad compaction
     (dest ``start + j`` reads padded row ``j + pad``) — so compacted K/V
@@ -55,22 +58,24 @@ from repro.kernels.flash_decode.flash_decode import _head_col, _pv
 NEG_INF = -1e30
 
 
-def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
-                    kp_ref, vp_ref, *rest, bs: int, prefix: int,
+def _prefill_kernel(len_ref, start_ref, tbl_ref, lyr_ref, q_ref, kn_ref,
+                    vn_ref, kp_ref, vp_ref, *rest, bs: int, prefix: int,
                     t_read: int, sm_scale: float, kv_dtype=None):
     """One program = one grid step of one row, all of its KV heads.
 
-    len/start (B,) and tbl (B, T): scalar-prefetch SMEM (the table also
-    drives the pool index maps); q_ref (Hk, S*rep, D); kn/vn_ref
-    (bs + S + bs, Hk, D): the chunk's rotated K/V between ``bs`` zero rows
-    on each side; kp/vp_ref (bs, Hk, D): this
-    step's pool block resolved through the table — cached context on read
-    steps, the scatter destination's old content on write steps; o_ref
-    (Hk, S*rep, D); ko/vo_ref (bs, Hk, D): the (aliased) pool block being
-    written back.  acc/m/l: VMEM scratch carrying each head's online
-    softmax across the (innermost, sequential) grid dimension.  Heads are
-    a static loop: a pool block carries all of them because the TPU
-    lowering needs a block's last two dims (Hk, D) whole.
+    len/start (B,), tbl (B, T) and lyr (1,): scalar-prefetch SMEM (the
+    table and the layer index also drive the pool index maps); q_ref
+    (Hk, S*rep, D); kn/vn_ref (bs + S + bs, Hk, D): the chunk's rotated
+    K/V between ``bs`` zero rows on each side (rows on a leading dim, so a
+    block's new rows are a dynamic slice at any offset); kp/vp_ref
+    (bs, Hk*D): this step's pool block resolved through (layer, table) —
+    cached context on read steps, the scatter destination's old content
+    on write steps; o_ref (Hk, S*rep, D); ko/vo_ref (bs, Hk*D): the
+    (aliased) pool block being written back.  acc/m/l: VMEM scratch
+    carrying each head's online softmax across the (innermost,
+    sequential) grid dimension.  Heads are a static loop over lane ranges
+    [h*D, (h+1)*D): a pool block carries all of them because the TPU
+    lowering needs a block's last two dims (bs, Hk*D) whole.
 
     ``kv_dtype`` ("int8"/"fp8"; None = fp pool) switches on the SCLAD
     layout: ksp/vsp_ref and kso/vso_ref carry the (bs, Hk) per-position
@@ -101,21 +106,29 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     start = start_ref[b]
     pad = P - length
 
-    def fake_quant(x):
-        """fp32 (rows, D) -> the value a pool reader will observe: the
-        round-trip of ``kv_quant.quantize``/``dequantize`` without the
-        payload-dtype container (exact for int8 — round() already yields
-        the representable integral grid — and an actual f8 cast for fp8).
-        """
+    def scale_of(x):  # kv_quant.quantize's scale, op-for-op: (rows, 1)
         amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
         # Constant multiply, not division — matches kv_quant.quantize
         # bitwise in every tracing context (XLA rewrites /const under jit).
-        scale = jnp.where(amax > 0, amax * (1.0 / qm), 1.0)
+        return jnp.where(amax > 0, amax * (1.0 / qm), 1.0)
+
+    def quantize(x):
+        """fp32 (rows, D) -> ``kv_quant.quantize``'s payload values, in
+        fp32 (exact for int8 — round() already yields the representable
+        integral grid — and an actual f8 cast for fp8), and scales."""
+        scale = scale_of(x)
         qv = x / scale
         if kv_dtype == "int8":
             qv = jnp.round(qv)
         else:
             qv = qv.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+        return qv, scale
+
+    def fake_quant(x):
+        """fp32 (rows, D) -> the value a pool reader will observe: the
+        round-trip of ``kv_quant.quantize``/``dequantize`` without the
+        payload-dtype container."""
+        qv, scale = quantize(x)
         return qv * scale
 
     @pl.when(i == 0)
@@ -144,8 +157,8 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
         pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         for h in range(Hk):
             q = q_ref[h].astype(jnp.float32) * sm_scale
-            k = kp_ref[:, h, :].astype(jnp.float32)
-            v = vp_ref[:, h, :]
+            k = kp_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
+            v = vp_ref[:, h * D:(h + 1) * D]
             if kv_dtype is not None:
                 # Load-as-Dense: (bs, D) payload * (bs, 1) scale in fp32,
                 # then ROUNDED to the compute dtype — the reference's
@@ -184,58 +197,54 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
     # Scatter phase: merge one destination block.  Offset o holds cache
     # position w*bs + o = start + j; compacted index j maps back to padded
     # source row j (vlm prefix) or j + pad (prompt tokens) — for each of
-    # the two pieces a CONTIGUOUS run of chunk rows, so the block's new
-    # rows (all heads) are one dynamic leading-dim slice of the chunk's
-    # K/V (an exact copy).  The chunk carries ``bs`` zero rows on each
-    # side, so every slice start clamps into bounds without moving a
-    # valid row.
+    # the two pieces a CONTIGUOUS run of chunk rows, so a head's new rows
+    # are one dynamic leading-dim slice of the chunk's K/V (an exact copy)
+    # written to the head's lane range of the block.  The chunk carries
+    # ``bs`` zero rows on each side, so every slice start clamps into
+    # bounds without moving a valid row.
     @pl.when(i >= t_read)
     def _scatter():
         w = jnp.minimum(start // bs + (i - t_read), T - 1)
         base = w * bs - start  # chunk row of offset 0, before the pad
+        o = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+        j = w * bs + o - start
+        valid = (j >= 0) & (j < prefix + length)
 
-        def new_rows(ref, j, *head):
-            # The block's new rows: (bs, Hk, D), or (bs, D) for one head.
+        def new_rows(ref, h):
+            # Head h's new rows of the block: (bs, D).
             def take(off):
-                return ref[(pl.ds(jnp.clip(off + bs, 0, S + bs), bs),)
-                           + head]
+                return ref[pl.ds(jnp.clip(off + bs, 0, S + bs), bs), h, :]
             new = take(base + pad)
             if prefix:
                 new = jnp.where(j < prefix, take(base), new)
             return new.astype(jnp.float32)
 
-        def scale_of(xf):  # kv_quant.quantize's scale, op-for-op
-            amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-            return jnp.where(amax > 0, amax * (1.0 / qm), 1.0)
-
         def merge(ref, out_ref, old_ref, sc_out, sc_old):
-            o3 = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
-            j3 = w * bs + o3 - start
-            valid3 = (j3 >= 0) & (j3 < prefix + length)
-            new = new_rows(ref, j3)
             if kv_dtype is not None:
-                # kv_quant.quantize, op-for-op, from the exact fp32 view
-                # of the chunk's rows — payload and scales match the
-                # host-side reference scatter bitwise.  Invalid rows
-                # quantize garbage, but the merge passes the OLD payload
-                # and scale through.
-                new = new / scale_of(new)
-                if kv_dtype == "int8":
-                    new = jnp.round(new)
-                # The (bs, Hk) scale tile, one head column at a time from
-                # the same rows (the same values as the 3D scale above).
-                o = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-                j = w * bs + o - start
-                put = (j >= 0) & (j < prefix + length)
-                lane = jax.lax.broadcasted_iota(jnp.int32, (bs, Hk), 1)
+                head = jax.lax.broadcasted_iota(jnp.int32, (bs, Hk), 1)
                 sc = sc_old[...]
-                for h in range(Hk):
-                    sc = jnp.where(put & (lane == h),
-                                   scale_of(new_rows(ref, j, h, slice(None))),
-                                   sc)
+            for h in range(Hk):
+                lanes = slice(h * D, (h + 1) * D)
+                new = new_rows(ref, h)
+                old = old_ref[:, lanes]
+                if kv_dtype is None:
+                    out_ref[:, lanes] = jnp.where(
+                        valid, new.astype(out_ref.dtype), old)
+                    continue
+                # kv_quant.quantize, op-for-op, from the exact fp32 view
+                # of the chunk's rows: payload and scales match the
+                # reference scatter bitwise.  Invalid rows quantize
+                # garbage, but the merge passes the OLD payload and scale
+                # through.  The merge runs in fp32 and the payload is cast
+                # once after it: with the select on the int8 cast's output
+                # the chip let an invalid row's +127 through into a kept
+                # row.
+                new, scale = quantize(new)
+                sc = jnp.where(valid & (head == h), scale, sc)
+                out_ref[:, lanes] = jnp.where(
+                    valid, new, old.astype(jnp.float32)).astype(out_ref.dtype)
+            if kv_dtype is not None:
                 sc_out[...] = sc
-            out_ref[...] = jnp.where(valid3, new.astype(out_ref.dtype),
-                                     old_ref[...])
 
         merge(kn_ref, ko_ref, kp_ref, kso_ref, ksp_ref)
         merge(vn_ref, vo_ref, vp_ref, vso_ref, vsp_ref)
@@ -250,7 +259,7 @@ def _prefill_kernel(len_ref, start_ref, tbl_ref, q_ref, kn_ref, vn_ref,
                    static_argnames=("prefix", "has_ctx", "interpret",
                                     "kv_dtype"))
 def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
-                        block_tables, start, *, prefix: int = 0,
+                        block_tables, start, layer=0, *, prefix: int = 0,
                         has_ctx: bool = True, interpret: bool = False,
                         kv_scales=None, kv_dtype=None):
     """Chunked-prefill attention + fused K/V scatter on the paged pool.
@@ -258,19 +267,22 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
     q:             (B, S, H, D) rotated chunk queries (S = prefix + P,
                    prompt tokens LEFT-padded to P);
     k_new/v_new:   (B, S, Hk, D) the chunk's rotated K/V (compute dtype);
-    k_pool/v_pool: (N, bs, Hk, D) the SHARED block pool
-                   (``model.init_paged_cache`` layout, trash block
-                   included) — updated in place via
+    k_pool/v_pool: (L, N, bs, Hk*D) the SHARED lane-dense block pool of
+                   every layer (``model.init_paged_cache`` layout, trash
+                   block included) — updated in place via
                    ``input_output_aliases``;
     lengths:       (B,) int32 true chunk token count per row (<= P);
     block_tables:  (B, T) int32 per-lane tables (unallocated entries point
                    at the trash block);
     start:         (B,) int32 cache positions already filled per row;
+    layer:         int32 scalar — the layer whose slab is read and
+                   written (scalar-prefetched into the index maps; every
+                   other layer's bytes pass through untouched);
     prefix:        static vlm patch-prefix length (first chunk only);
     has_ctx:       static — False for first chunks (start == 0 rows): the
                    table-walk read phase is dropped from the grid;
-    kv_scales:     optional (k_scale, v_scale) (N, bs, Hk) fp32 scales of a
-                   SCLAD quantized pool, with static ``kv_dtype``
+    kv_scales:     optional (k_scale, v_scale) (L, N, bs, Hk) fp32 scales
+                   of a SCLAD quantized pool, with static ``kv_dtype``
                    ("int8"/"fp8") naming the payload encoding.  The scales
                    ride the same table-walked BlockSpecs as the payload
                    (one (bs, Hk) tile per step) and are
@@ -287,7 +299,7 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
     B, S, H, D = q.shape
     Hk = k_new.shape[2]
     rep = H // Hk
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     T = block_tables.shape[1]
     sm_scale = 1.0 / math.sqrt(D)
     quantized = kv_scales is not None
@@ -307,30 +319,28 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
     w_steps = min(T, -(-S // bs) + 1)
     grid = (B, t_read + w_steps)
 
-    def pool_read_blk(b, i, lens, starts, tbl):
+    def pool_read_blk(b, i, lens, starts, tbl, lyr):
         wr = jnp.minimum(starts[b] // bs + (i - t_read), T - 1)
         idx = jnp.where(i < t_read, jnp.minimum(i, T - 1), wr)
-        return tbl[b, idx], 0, 0, 0
+        return lyr[0], tbl[b, idx], 0, 0
 
-    def pool_write_blk(b, i, lens, starts, tbl):
+    def pool_write_blk(b, i, lens, starts, tbl, lyr):
         # Parked on the FIRST write block during the read phase so the
         # (unwritten) output buffer is never flushed over a context block.
         j = jnp.maximum(i - t_read, 0)
-        return tbl[b, jnp.minimum(starts[b] // bs + j, T - 1)], 0, 0, 0
+        return lyr[0], tbl[b, jnp.minimum(starts[b] // bs + j, T - 1)], 0, 0
 
-    def row(b, i, lens, starts, tbl):
+    def row(b, i, lens, starts, tbl, lyr):
         return b, 0, 0, 0
 
     # A pool block carries every KV head and a scale block its (bs, Hk)
     # tile: the TPU lowering accepts a block whose last two dims are whole.
     q_blk = pl.BlockSpec((None, Hk, S * rep, D), row)
     seq_blk = pl.BlockSpec((None, S + 2 * bs, Hk, D), row)
-    pool_rd = pl.BlockSpec((None, bs, Hk, D), pool_read_blk)
-    pool_wr = pl.BlockSpec((None, bs, Hk, D), pool_write_blk)
-    scale_rd = pl.BlockSpec((None, bs, Hk),
-                            lambda *a: pool_read_blk(*a)[:3])
-    scale_wr = pl.BlockSpec((None, bs, Hk),
-                            lambda *a: pool_write_blk(*a)[:3])
+    pool_rd = pl.BlockSpec((None, None, bs, Hk * D), pool_read_blk)
+    pool_wr = pl.BlockSpec((None, None, bs, Hk * D), pool_write_blk)
+    scale_rd = pl.BlockSpec((None, None, bs, Hk), pool_read_blk)
+    scale_wr = pl.BlockSpec((None, None, bs, Hk), pool_write_blk)
 
     in_specs = [q_blk, seq_blk, seq_blk, pool_rd, pool_rd]
     out_specs = [q_blk, pool_wr, pool_wr]
@@ -340,12 +350,13 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
         jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
     ]
     inputs = [jnp.asarray(lengths, jnp.int32), jnp.asarray(start, jnp.int32),
-              jnp.asarray(block_tables, jnp.int32), qt, knt, vnt,
+              jnp.asarray(block_tables, jnp.int32),
+              jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), qt, knt, vnt,
               k_pool, v_pool]
     # Flat input indices (scalar-prefetch leaves included): pools are
-    # inputs 6/7 -> outputs 1/2 (and scales 8/9 -> 3/4 when quantized), so
-    # every pool update happens in place.
-    aliases = {6: 1, 7: 2}
+    # inputs 7/8 -> outputs 1/2 (and scales 9/10 -> 3/4 when quantized),
+    # so every pool update happens in place.
+    aliases = {7: 1, 8: 2}
     if quantized:
         k_scale, v_scale = (x.astype(jnp.float32) for x in kv_scales)
         in_specs += [scale_rd, scale_rd]
@@ -353,10 +364,10 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, lengths,
         out_shape += [jax.ShapeDtypeStruct(k_scale.shape, jnp.float32),
                       jax.ShapeDtypeStruct(v_scale.shape, jnp.float32)]
         inputs += [k_scale, v_scale]
-        aliases = {6: 1, 7: 2, 8: 3, 9: 4}
+        aliases = {7: 1, 8: 2, 9: 3, 10: 4}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # lengths, start, block_tables
+        num_scalar_prefetch=4,  # lengths, start, block_tables, layer
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

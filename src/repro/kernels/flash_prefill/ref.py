@@ -3,8 +3,9 @@
 ``prefill_attention_ref`` reproduces the pre-kernel ``model.prefill_slots``
 per-layer arithmetic EXACTLY:
 
-  * the cached-context gather ``k_pool[block_tables]`` materializing the
-    dense (B, T*bs, Hk, D) per-lane copy the kernel exists to avoid,
+  * the cached-context gather ``k_pool[layer, block_tables]``
+    materializing the dense (B, T*bs, Hk, D) per-lane copy the kernel
+    exists to avoid,
   * the dense (B, S, S) causal/left-pad mask and its (B, S, T*bs) context
     extension,
   * ``models.layers._sdpa`` arithmetic (compute-dtype score einsum, fp32
@@ -32,15 +33,16 @@ def prefill_attention_ref(q, k_new, v_new, k_pool, v_pool, lengths,
                           block_tables,
                           start: Optional[jnp.ndarray] = None,
                           prefix: int = 0,
-                          kv_scales=None, kv_dtype: Optional[str] = None):
+                          kv_scales=None, kv_dtype: Optional[str] = None,
+                          layer=0):
     """One layer of chunked-prefill attention against a paged KV pool.
 
     q:             (B, S, H, D)  rotated queries of this chunk (S = prefix
                    + P: an optional vlm patch prefix plus P LEFT-padded
                    prompt tokens);
     k_new/v_new:   (B, S, Hk, D) this chunk's rotated K/V (compute dtype);
-    k_pool/v_pool: (N, bs, Hk, D) the shared block pool (pool storage
-                   dtype; trash block included);
+    k_pool/v_pool: (L, N, bs, Hk*D) the shared lane-dense block pool of
+                   every layer (pool storage dtype; trash block included);
     lengths:       (B,) int32 true token count of the chunk (<= P);
     block_tables:  (B, T) int32 per-lane tables;
     start:         None => first chunk (rows start at cache position 0, no
@@ -48,8 +50,8 @@ def prefill_attention_ref(q, k_new, v_new, k_pool, v_pool, lengths,
                    filled per row — the chunk attends to positions
                    [0, start) gathered through the table;
     prefix:        static vlm patch-prefix length (first chunk only);
-    kv_scales:     optional (k_scale, v_scale) (N, bs, Hk) fp32 scales of a
-                   SCLAD quantized pool, with ``kv_dtype`` ("int8"/"fp8")
+    kv_scales:     optional (k_scale, v_scale) (L, N, bs, Hk) fp32 scales
+                   of a SCLAD quantized pool, with ``kv_dtype`` ("int8"/"fp8")
                    naming the payload encoding.  Quantized semantics: the
                    gathered context payload is dequantized on load, the
                    chunk's OWN in-flight K/V is fake-quantized before
@@ -57,7 +59,8 @@ def prefill_attention_ref(q, k_new, v_new, k_pool, v_pool, lengths,
                    ``dequantize(quantize(x))`` — in-chunk and from-pool
                    scoring agree, so greedy bit-identity across chunk
                    sizes / prefix hits / preemption recomputes survives
-                   quantization), and the scatter writes payload + scales.
+                   quantization), and the scatter writes payload + scales;
+    layer:         int32 scalar, the pool layer read and written.
 
     Returns (attn_out (B, S, H*D) in q.dtype, k_pool', v_pool') with the
     chunk's new K/V left-compacted and scattered through the table at
@@ -91,13 +94,13 @@ def prefill_attention_ref(q, k_new, v_new, k_pool, v_pool, lengths,
     if start is not None:
         # Dense per-lane context gather — the O(B*T*bs*Hk*D) copy this
         # oracle pins and the kernel path provably never materializes.
-        bs = k_pool.shape[1]
-        kg = k_pool[block_tables].reshape(B, -1, *k_pool.shape[2:])
-        vg = v_pool[block_tables].reshape(B, -1, *v_pool.shape[2:])
+        bs = k_pool.shape[2]
+        kg = k_pool[layer, block_tables].reshape(B, -1, Hk, D)
+        vg = v_pool[layer, block_tables].reshape(B, -1, Hk, D)
         if quantized:
             k_scale, v_scale = kv_scales
-            ksg = k_scale[block_tables].reshape(B, -1, Hk)
-            vsg = v_scale[block_tables].reshape(B, -1, Hk)
+            ksg = k_scale[layer, block_tables].reshape(B, -1, Hk)
+            vsg = v_scale[layer, block_tables].reshape(B, -1, Hk)
             kg = kv_quant.dequantize(kg, ksg, q.dtype)
             vg = kv_quant.dequantize(vg, vsg, q.dtype)
         ctx_len = block_tables.shape[1] * bs
@@ -120,23 +123,26 @@ def prefill_attention_ref(q, k_new, v_new, k_pool, v_pool, lengths,
         k_pool, v_pool, k_scale, v_scale = scatter_new_kv_ref(
             k_new, v_new, k_pool, v_pool, lengths, block_tables,
             start=start, prefix=prefix, kv_scales=kv_scales,
-            kv_dtype=kv_dtype)
+            kv_dtype=kv_dtype, layer=layer)
         return out, k_pool, v_pool, k_scale, v_scale
     k_pool, v_pool = scatter_new_kv_ref(k_new, v_new, k_pool, v_pool,
                                         lengths, block_tables,
-                                        start=start, prefix=prefix)
+                                        start=start, prefix=prefix,
+                                        layer=layer)
     return out, k_pool, v_pool
 
 
 def scatter_new_kv_ref(k_new, v_new, k_pool, v_pool, lengths, block_tables,
                        start: Optional[jnp.ndarray] = None, prefix: int = 0,
-                       kv_scales=None, kv_dtype: Optional[str] = None):
+                       kv_scales=None, kv_dtype: Optional[str] = None,
+                       layer=0):
     """Host-side new-token K/V scatter (the ``attn_kernel="off"`` write
     path, bit-exact with the pre-fusion ``prefill_slots`` epilogue).
 
     Left-compacts each row's token K/V — real tokens to offsets 0..len-1
     after the prefix — then scatters through the block table at cache
-    positions ``start + i``.  Junk-tail entries are redirected out of
+    positions ``start + i`` of pool layer ``layer``, as lane-dense
+    (Hk*D) rows.  Junk-tail entries are redirected out of
     bounds and dropped so they cannot touch another row's blocks.
 
     With ``kv_scales`` + ``kv_dtype`` (SCLAD pool) the compacted rows are
@@ -146,7 +152,7 @@ def scatter_new_kv_ref(k_new, v_new, k_pool, v_pool, lengths, block_tables,
     (k_pool, v_pool, k_scale, v_scale).
     """
     B, S = k_new.shape[0], k_new.shape[1]
-    N, bs = k_pool.shape[0], k_pool.shape[1]
+    N, bs = k_pool.shape[1], k_pool.shape[2]
     T = block_tables.shape[1]
     P = S - prefix
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -173,11 +179,15 @@ def scatter_new_kv_ref(k_new, v_new, k_pool, v_pool, lengths, block_tables,
         k_scale, v_scale = kv_scales
         kq, ks1 = kv_quant.quantize(compact(k_new), kv_dtype)
         vq, vs1 = kv_quant.quantize(compact(v_new), kv_dtype)
-        k_pool = k_pool.at[blk, off].set(kq, mode="drop")
-        v_pool = v_pool.at[blk, off].set(vq, mode="drop")
-        k_scale = k_scale.at[blk, off].set(ks1, mode="drop")
-        v_scale = v_scale.at[blk, off].set(vs1, mode="drop")
+        k_pool = k_pool.at[layer, blk, off].set(kq.reshape(B, S, -1),
+                                                mode="drop")
+        v_pool = v_pool.at[layer, blk, off].set(vq.reshape(B, S, -1),
+                                                mode="drop")
+        k_scale = k_scale.at[layer, blk, off].set(ks1, mode="drop")
+        v_scale = v_scale.at[layer, blk, off].set(vs1, mode="drop")
         return k_pool, v_pool, k_scale, v_scale
-    k_pool = k_pool.at[blk, off].set(compact(k_new).astype(kvd), mode="drop")
-    v_pool = v_pool.at[blk, off].set(compact(v_new).astype(kvd), mode="drop")
+    k_pool = k_pool.at[layer, blk, off].set(
+        compact(k_new).reshape(B, S, -1).astype(kvd), mode="drop")
+    v_pool = v_pool.at[layer, blk, off].set(
+        compact(v_new).reshape(B, S, -1).astype(kvd), mode="drop")
     return k_pool, v_pool

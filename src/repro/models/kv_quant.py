@@ -35,12 +35,13 @@ the quantized pool or seen in-chunk — preserving the serving engine's
 greedy bit-identity matrix (prefix cache on/off, chunk sizes, preemption
 recompute) under quantization.
 
-Scales are per (token position, kv head): shape ``pool.shape[:-1]`` — for
-the (N, bs, Hk, D) pool that is (N, bs, Hk) fp32.  Per-head granularity
-matches the "per-block-per-head scale metadata" the CC-MEM decompressor
-would hold; per-position granularity is what keeps writes path-independent
-(a per-block amax would depend on write history and stale recycled
-content).  fp8 payloads reuse the float8_e4m3fn dtype the dense-cache
+Scales are per (token position, kv head): ``quantize`` of a (..., Hk, D)
+view returns (..., Hk) — for the lane-dense (L, N, bs, Hk*D) pool that is
+(L, N, bs, Hk) fp32, and the payload is stored back lane-dense.  Per-head
+granularity matches the "per-block-per-head scale metadata" the CC-MEM
+decompressor would hold; per-position granularity is what keeps writes
+path-independent (a per-block amax would depend on write history and stale
+recycled content).  fp8 payloads reuse the float8_e4m3fn dtype the dense-cache
 ``kv_dtype="f8"`` path already ships.
 """
 from __future__ import annotations

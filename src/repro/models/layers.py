@@ -269,6 +269,7 @@ def attention_decode(
     block_tables: Optional[jnp.ndarray] = None,
     kv_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     mesh=None,
+    layer=0,
 ):
     """Single-token decode with in-place cache update.
 
@@ -284,21 +285,24 @@ def attention_decode(
     Two cache layouts:
       * dense (block_tables=None): k_cache/v_cache are (B, S_max, Hk, D)
         slot stripes, row b's position j lives at [b, j];
-      * paged: k_cache/v_cache are (N, bs, Hk, D) pools of fixed-size token
-        blocks shared by all rows, and ``block_tables`` (B, T) int32 maps
-        row b's block index j//bs to a pool block (serving.paged hands these
-        out; unallocated entries point at the trash block).  The new K/V is
-        scattered through the table and the kernel walks each row's blocks
-        through the table directly out of the shared pool — no dense
-        per-lane copy of the pool is materialized on this path (the
-        ``"off"`` fallback gathers, as the pre-kernel engine did).  With
+      * paged: k_cache/v_cache are the lane-dense (L, N, bs, Hk*D) pools
+        of fixed-size token blocks of EVERY layer, shared by all rows;
+        ``layer`` (int32 scalar) names this layer's slab and
+        ``block_tables`` (B, T) int32 maps row b's block index j//bs to a
+        pool block (serving.paged hands these out; unallocated entries
+        point at the trash block).  The new K/V is written in place as one
+        (Hk*D) row at ``[layer, block, pos % bs]`` and the kernel walks
+        each row's blocks through (layer, table) directly out of the
+        shared pool — neither a per-layer slice nor a dense per-lane copy
+        of the pool is materialized on this path (the ``"off"`` fallback
+        gathers, as the pre-kernel engine did).  With
         prefix caching, SEVERAL rows' tables may name the same
         (ref-counted) block: concurrent reads are safe because the
         host-side store guarantees the scattered write position always
         lands in a block exclusive to its row (fresh growth or
         copy-on-write — ``BlockStore.ensure_writable``).
 
-    kv_scales: (k_scale, v_scale) (N, bs, Hk) fp32 leaves of a SCLAD
+    kv_scales: (k_scale, v_scale) (L, N, bs, Hk) fp32 leaves of a SCLAD
     quantized pool (paged layout only, ``cfg.kv_dtype`` in "int8"/"fp8").
     When given, the new token's K/V is quantized (``models.kv_quant``) and
     the payload + per-head scales scattered through the table; readers
@@ -332,33 +336,36 @@ def attention_decode(
             q[:, 0], k_cache.astype(x.dtype), v_cache.astype(x.dtype),
             lengths, kernel=cfg.attn_kernel)
     else:
-        bs = k_cache.shape[1]
+        bs = k_cache.shape[2]
         rows = jnp.arange(B)
         # Dead lanes carry all-trash tables, so their writes land in the
         # trash block and cannot clobber a block re-assigned to a live lane
         # (their stale ``lengths`` only ever cover trash blocks, which the
         # caller's active mask keeps out of every live result).
-        blk = block_tables[rows, pos // bs]
+        at = (layer, block_tables[rows, pos // bs], pos % bs)
         if kv_scales is not None:
             from repro.models import kv_quant
             k_scale, v_scale = kv_scales
             kq, ks1 = kv_quant.quantize(k[:, 0], cfg.kv_dtype)  # (B,Hk,D)/(B,Hk)
             vq, vs1 = kv_quant.quantize(v[:, 0], cfg.kv_dtype)
-            k_cache = k_cache.at[blk, pos % bs].set(kq)
-            v_cache = v_cache.at[blk, pos % bs].set(vq)
-            k_scale = k_scale.at[blk, pos % bs].set(ks1)
-            v_scale = v_scale.at[blk, pos % bs].set(vs1)
+            k_cache = k_cache.at[at].set(kq.reshape(B, -1))
+            v_cache = v_cache.at[at].set(vq.reshape(B, -1))
+            k_scale = k_scale.at[at].set(ks1)
+            v_scale = v_scale.at[at].set(vs1)
             out = decode_ops.decode_attention(
                 q[:, 0], k_cache, v_cache, lengths,
-                block_tables=block_tables, kernel=cfg.attn_kernel,
-                kv_scales=(k_scale, v_scale), mesh=mesh)
+                block_tables=block_tables, layer=layer,
+                kernel=cfg.attn_kernel, kv_scales=(k_scale, v_scale),
+                mesh=mesh)
             return (out.reshape(B, 1, -1).astype(x.dtype) @ p["wo"],
                     k_cache, v_cache, k_scale, v_scale)
-        k_cache = k_cache.at[blk, pos % bs].set(k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[blk, pos % bs].set(v[:, 0].astype(v_cache.dtype))
+        k_cache = k_cache.at[at].set(k[:, 0].reshape(B, -1)
+                                     .astype(k_cache.dtype))
+        v_cache = v_cache.at[at].set(v[:, 0].reshape(B, -1)
+                                     .astype(v_cache.dtype))
         out = decode_ops.decode_attention(
             q[:, 0], k_cache, v_cache, lengths, block_tables=block_tables,
-            kernel=cfg.attn_kernel, mesh=mesh)
+            layer=layer, kernel=cfg.attn_kernel, mesh=mesh)
     return out.reshape(B, 1, -1).astype(x.dtype) @ p["wo"], k_cache, v_cache
 
 

@@ -427,16 +427,21 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
                      block_size: int, mesh=None) -> Params:
     """KV cache as a pool of fixed-size token blocks (attention families).
 
-    Layout (L, num_blocks, block_size, Hk, hd): block ``b`` holds
-    ``block_size`` consecutive token positions of whichever sequence(s)
-    reference it per the host-side ``serving.paged.BlockStore`` — with
-    prefix caching a block can appear in SEVERAL lanes' tables at once
-    (ref-counted, read-only sharing), and retired blocks keep their payload
-    while they sit in the store's LRU pool.  Block 0 is the trash block
-    dead lanes write into.  ``layers.attention_decode`` and
-    ``prefill_slots`` address the pool through per-row block tables; writes
-    must target blocks the store reports exclusive (the engine's
-    copy-on-write barrier guarantees this — see ``copy_cache_block``).
+    Layout (L, num_blocks, block_size, Hk*hd), lane-dense: row ``o`` of
+    block ``b`` in layer ``l`` holds one token's K (or V) for every KV head,
+    head h in lanes [h*hd, (h+1)*hd).  Block ``b`` holds ``block_size``
+    consecutive token positions of whichever sequence(s) reference it per
+    the host-side ``serving.paged.BlockStore`` — with prefix caching a
+    block can appear in SEVERAL lanes' tables at once (ref-counted,
+    read-only sharing), and retired blocks keep their payload while they
+    sit in the store's LRU pool.  Block 0 is the trash block dead lanes
+    write into.  ``layers.attention_decode`` and ``prefill_slots`` address
+    the pool through per-row block tables and a layer index, and carry the
+    whole stacked pool through the layer scan: no step slices, restacks or
+    relays out the pool (the lane-dense rows are what the Pallas kernels
+    DMA, so the device layout needs no relayout for them).  Writes must
+    target blocks the store reports exclusive (the engine's copy-on-write
+    barrier guarantees this — see ``copy_cache_block``).
 
     With a SCLAD ``cfg.kv_dtype`` ("int8" / "fp8") the pool is stored
     compressed: the k/v leaves hold the quantized payload and two extra
@@ -448,22 +453,24 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
     over the (payload, scale) pair as one unit.
 
     With a ``mesh`` the pool is placed per ``cache_specs(paged=True)``:
-    payload and scale leaves co-sharded on the KV-head axis over ``model``
-    (so the shard_map'd kernels dequantize locally), everything else
-    replicated.  ``sanitize_specs`` drops the head sharding when Hk does
-    not divide the axis — the same gate ``attn_shard_size`` applies at
-    dispatch, so placement and dispatch always agree.
+    payload and scale leaves co-sharded on their KV heads over ``model``
+    (contiguous head ranges of the lane axis, so the shard_map'd kernels
+    dequantize locally), everything else replicated.  The head sharding is
+    dropped when Hk does not divide the axis — the same gate
+    ``attn_shard_size`` applies at dispatch, so placement and dispatch
+    always agree.
     """
     fam = cfg.family
     if fam not in ("dense", "moe", "vlm"):
         raise NotImplementedError(
             f"paged KV caches cover the attention families, not {fam!r}")
     hk, hd, L = cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
+    rows = (L, num_blocks, block_size, hk * hd)
     if kv_quant.is_quantized(cfg.kv_dtype):
         KVD = kv_quant.payload_dtype(cfg.kv_dtype)
         cache = {
-            "k": jnp.zeros((L, num_blocks, block_size, hk, hd), KVD),
-            "v": jnp.zeros((L, num_blocks, block_size, hk, hd), KVD),
+            "k": jnp.zeros(rows, KVD),
+            "v": jnp.zeros(rows, KVD),
             # All-zero payload rows carry scale 1.0 by the quantizer's
             # convention; zeros-init matches (0 * 1.0 == 0) but any init
             # works — unwritten positions are masked by lengths.
@@ -474,16 +481,21 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
         }
     else:
         KVD = kv_store_dtype(cfg)
-        cache = {
-            "k": jnp.zeros((L, num_blocks, block_size, hk, hd), KVD),
-            "v": jnp.zeros((L, num_blocks, block_size, hk, hd), KVD),
-        }
+        cache = {"k": jnp.zeros(rows, KVD), "v": jnp.zeros(rows, KVD)}
     if mesh is None:
         return cache
     with sharding.use_axes(mesh):
         specs = sharding.cache_specs(cfg, cache, None, 1, paged=True)
         specs = sharding.sanitize_specs(specs, cache)
     return jax.device_put(cache, sharding.to_shardings(mesh, specs))
+
+
+def _pool_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The paged pool's leaves in kernel argument order: payload, then the
+    SCLAD scales of a quantized pool."""
+    if kv_quant.is_quantized(cfg.kv_dtype):
+        return ("k", "v", "k_scale", "v_scale")
+    return ("k", "v")
 
 
 def cache_specs(cfg: ModelConfig, batch: int, max_len: int):
@@ -547,14 +559,16 @@ def prefill_slots(cfg: ModelConfig, params: Params, cache: Params,
     is read through the block table.  The writes land strictly at
     positions >= ``start``, i.e. past every shared block.
 
-    Per layer, the attention core AND the new-token K/V scatter dispatch
-    through ``kernels.flash_prefill.ops.prefill_attention``, selected by
-    ``cfg.attn_kernel``: on the kernel path the cached context is streamed
-    block-by-block straight out of the shared pool (scalar-prefetched
-    table walk — no dense per-lane ``k_pool[block_tables]`` copy, no dense
-    (Bn, S, S) mask) and the compacted chunk K/V is scattered into the
-    pool inside the same kernel invocation; the reference path gathers and
-    scatters host-side, bit-exact with the pre-kernel engine.
+    The whole stacked pool rides the layer scan's carry.  Per layer, the
+    attention core AND the new-token K/V scatter dispatch through
+    ``kernels.flash_prefill.ops.prefill_attention`` at that layer's index,
+    selected by ``cfg.attn_kernel``: on the kernel path the cached context
+    is streamed block-by-block straight out of the shared pool
+    (scalar-prefetched table walk — no dense per-lane
+    ``k_pool[layer, block_tables]`` copy, no dense (Bn, S, S) mask) and the
+    compacted chunk K/V is scattered into the pool inside the same kernel
+    invocation; the reference path gathers and scatters host-side,
+    bit-exact with the pre-kernel engine.
 
     tokens:  (Bn, P) int32, each row's chunk LEFT-padded to P;
     lengths: (Bn,) true token count of this chunk (<= P);
@@ -630,37 +644,27 @@ def prefill_slots(cfg: ModelConfig, params: Params, cache: Params,
     lengths = jnp.asarray(lengths, jnp.int32)
 
     quantized = kv_quant.is_quantized(cfg.kv_dtype)
+    names = _pool_leaves(cfg)
 
-    if quantized:
-        def body(x, blk_kv):
-            blk, kc, vc, ksc, vsc = blk_kv
-            q, k, v = _attn_qkv(cfg, blk, x, positions)
-            a, kc, vc, ksc, vsc = prefill_ops.prefill_attention(
-                q, k, v, kc, vc, lengths, block_tables,
-                start=None if first else start_v, prefix=prefix,
-                kernel=cfg.attn_kernel, kv_scales=(ksc, vsc),
-                kv_dtype=cfg.kv_dtype, mesh=mesh)
-            x, _ = _attn_post(cfg, blk, x, a, moe_valid=real_key)
-            return x, (kc, vc, ksc, vsc)
+    # The whole stacked pool rides the scan carry: each layer's kernel (or
+    # reference) reads and writes its slab in place through the layer
+    # index, so no per-layer slice or restack of the pool is made.
+    def body(carry, blk_layer):
+        x, pool = carry
+        blk, layer = blk_layer
+        q, k, v = _attn_qkv(cfg, blk, x, positions)
+        a, *pool = prefill_ops.prefill_attention(
+            q, k, v, pool[0], pool[1], lengths, block_tables, layer=layer,
+            start=None if first else start_v, prefix=prefix,
+            kernel=cfg.attn_kernel, kv_scales=tuple(pool[2:]) or None,
+            kv_dtype=cfg.kv_dtype if quantized else None, mesh=mesh)
+        x, _ = _attn_post(cfg, blk, x, a, moe_valid=real_key)
+        return (x, tuple(pool)), None
 
-        h, (ks, vs, kss, vss) = jax.lax.scan(
-            body, h, (params["blocks"], cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        cache = dict(cache, k=ks, v=vs, k_scale=kss, v_scale=vss)
-    else:
-        def body(x, blk_kv):
-            blk, kc, vc = blk_kv
-            q, k, v = _attn_qkv(cfg, blk, x, positions)
-            a, kc, vc = prefill_ops.prefill_attention(
-                q, k, v, kc, vc, lengths, block_tables,
-                start=None if first else start_v, prefix=prefix,
-                kernel=cfg.attn_kernel, mesh=mesh)
-            x, _ = _attn_post(cfg, blk, x, a, moe_valid=real_key)
-            return x, (kc, vc)
-
-        h, (ks, vs) = jax.lax.scan(
-            body, h, (params["blocks"], cache["k"], cache["v"]))
-        cache = dict(cache, k=ks, v=vs)
+    (h, pool), _ = jax.lax.scan(
+        body, (h, tuple(cache[n] for n in names)),
+        (params["blocks"], jnp.arange(cfg.num_layers)))
+    cache = dict(cache, **dict(zip(names, pool)))
     # Left padding aligns every row's last REAL token at index S-1.
     if all_logits:
         logits = unembed(cfg, params, h[:, prefix:])  # (Bn, P, vocab)
@@ -687,12 +691,14 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
 
     block_tables: optional (B, T) int32 — the cache is a paged block pool
     (``init_paged_cache``) addressed per row through this table instead of
-    a dense (L, B, ctx) stripe (dense/moe/vlm only).  Per layer, attention
-    reads dispatch through ``kernels.flash_decode.ops.decode_attention``
-    with the (pool, block_tables, lengths = position + 1) calling
+    a dense (L, B, ctx) stripe (dense/moe/vlm only).  The whole stacked
+    pool rides the layer scan's carry; per layer, attention reads dispatch
+    through ``kernels.flash_decode.ops.decode_attention`` with the
+    (pool, block_tables, layer, lengths = position + 1) calling
     convention: on the kernel path (``cfg.attn_kernel``) each row's
-    blocks are walked through the table straight out of the shared pool —
-    no dense per-lane copy of the pool is materialized.
+    blocks are walked through (layer, table) straight out of the shared
+    pool — neither a per-layer slice nor a dense per-lane copy of the
+    pool is materialized.
 
     Returns (logits (B, 1, vocab), updated cache).
     """
@@ -705,8 +711,6 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
 
     if fam in ("dense", "moe", "vlm"):
         pos = position + (cfg.num_patches if fam == "vlm" else 0)
-        quantized = block_tables is not None \
-            and kv_quant.is_quantized(cfg.kv_dtype)
 
         def ffn(x, blk):
             if fam == "moe":
@@ -717,35 +721,39 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
             return x + layers.apply_mlp(
                 cfg, blk["mlp"], layers.apply_norm(cfg, blk["ln_mlp"], x))
 
-        if quantized:
-            def body(x, blk_kv):
-                blk, kc, vc, ksc, vsc = blk_kv
-                a, kc, vc, ksc, vsc = layers.attention_decode(
-                    cfg, blk["attn"],
-                    layers.apply_norm(cfg, blk["ln_attn"], x), kc, vc, pos,
-                    block_tables=block_tables, kv_scales=(ksc, vsc),
-                    mesh=mesh)
-                x = ffn(x + a, blk)
-                return x, (kc, vc, ksc, vsc)
-
-            h, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-                body, h, (params["blocks"], cache["k"], cache["v"],
-                          cache["k_scale"], cache["v_scale"]))
-            new_cache = {"k": k_new, "v": v_new,
-                         "k_scale": ks_new, "v_scale": vs_new}
-        else:
+        if block_tables is None:
             def body(x, blk_kv):
                 blk, kc, vc = blk_kv
                 a, kc, vc = layers.attention_decode(
                     cfg, blk["attn"],
-                    layers.apply_norm(cfg, blk["ln_attn"], x), kc, vc, pos,
-                    block_tables=block_tables, mesh=mesh)
+                    layers.apply_norm(cfg, blk["ln_attn"], x), kc, vc, pos)
                 x = ffn(x + a, blk)
                 return x, (kc, vc)
 
             h, (k_new, v_new) = jax.lax.scan(
                 body, h, (params["blocks"], cache["k"], cache["v"]))
             new_cache = {"k": k_new, "v": v_new}
+        else:
+            names = _pool_leaves(cfg)
+
+            # The whole stacked pool rides the scan carry (see
+            # prefill_slots): the new token's K/V is written in place at
+            # [layer, block, offset] and the read walks (layer, table).
+            def body(carry, blk_layer):
+                x, pool = carry
+                blk, layer = blk_layer
+                a, *pool = layers.attention_decode(
+                    cfg, blk["attn"],
+                    layers.apply_norm(cfg, blk["ln_attn"], x), pool[0],
+                    pool[1], pos, block_tables=block_tables,
+                    kv_scales=tuple(pool[2:]) or None, mesh=mesh,
+                    layer=layer)
+                return (ffn(x + a, blk), tuple(pool)), None
+
+            (h, pool), _ = jax.lax.scan(
+                body, (h, tuple(cache[n] for n in names)),
+                (params["blocks"], jnp.arange(cfg.num_layers)))
+            new_cache = dict(zip(names, pool))
     elif fam == "ssm":
         def body(x, blk_c):
             blk, c = blk_c

@@ -253,12 +253,17 @@ def cache_specs(cfg: ModelConfig, cache_shape, dp: Optional[Tuple[str, ...]],
                 batch: int, tp: str = "model", paged: bool = False) -> Any:
     """dp = batch axes (None to replicate small batches).
 
-    paged: the k/v leaves are block pools (L, N, bs, Hk, hd) rather than
-    dense (L, B, S, Hk, hd) stripes — any request's block table may point
-    anywhere in the pool, so the pool is NOT batch-shardable; shard the KV
-    heads over ``model`` instead (matches the decode attention TP layout).
+    paged: the k/v leaves are lane-dense block pools (L, N, bs, Hk*hd)
+    rather than dense (L, B, S, Hk, hd) stripes — any request's block
+    table may point anywhere in the pool, so the pool is NOT
+    batch-shardable; shard the KV heads over ``model`` instead (contiguous
+    head ranges of the merged lane axis, matching the decode attention TP
+    layout), and only where ``model`` divides Hk — the gate
+    ``attn_shard_size`` applies at dispatch.
     """
     dpa = dp if (dp and batch % _axes_size_hint(dp) == 0) else None
+    heads_tp = tp if paged and cfg.num_kv_heads % axis_size(tp) == 0 \
+        else None
 
     def rule(path, leaf):
         nd = len(leaf.shape)
@@ -266,13 +271,13 @@ def cache_specs(cfg: ModelConfig, cache_shape, dp: Optional[Tuple[str, ...]],
         name = path_s.rsplit("/", 1)[-1]
         if name in ("k", "v", "attn_k", "attn_v", "cross_k", "cross_v"):
             if paged:
-                return P(None, None, None, tp, None)
+                return P(None, None, None, heads_tp)
             # (L, B, S, Hk, hd): batch over dp, sequence over model.
             return P(None, dpa, tp, None, None)
         if name in ("k_scale", "v_scale") and paged:
             # SCLAD scale metadata (L, N, bs, Hk): co-sharded with the
-            # payload's KV-head axis so each shard dequantizes locally.
-            return P(None, None, None, tp)
+            # payload's KV heads so each shard dequantizes locally.
+            return P(None, None, None, heads_tp)
         if name == "state":
             # (..., B, H, P, N): heads over model.
             return P(*([None] * (nd - 4)), dpa, tp, None, None)
@@ -348,14 +353,15 @@ TP_AXIS = "model"
 def attn_shard_size(mesh, num_kv_heads: int, axis: str = TP_AXIS) -> int:
     """How many ways the paged attention dispatch can shard the KV-head axis.
 
-    The shard_map wrappers split the (N, bs, Hk, D) pool — payload AND
-    SCLAD scale leaves — plus the query head groups over ``axis``, with
-    everything host-derived (block tables, length/start vectors — the
-    kernels' scalar-prefetch operands) broadcast.  Returns 1 (single-device
-    dispatch, no wrapper) when there is no mesh, the mesh has no ``axis``
-    (or it is trivial), or ``num_kv_heads`` does not divide it evenly —
-    exactly the cases ``sanitize_specs`` drops the pool's head sharding
-    for, so cache placement and kernel dispatch always agree.
+    The shard_map wrappers split the (L, N, bs, Hk*D) pool's heads —
+    payload AND SCLAD scale leaves — plus the query head groups over
+    ``axis``, with everything host-derived (block tables, length/start
+    vectors, the layer index — the kernels' scalar-prefetch operands)
+    broadcast.  Returns 1 (single-device dispatch, no wrapper) when there
+    is no mesh, the mesh has no ``axis`` (or it is trivial), or
+    ``num_kv_heads`` does not divide it evenly — exactly the cases
+    ``cache_specs(paged=True)`` leaves the pool's heads unsharded for, so
+    cache placement and kernel dispatch always agree.
     """
     if mesh is None:
         return 1
@@ -369,7 +375,9 @@ def paged_attn_specs(axis: str = TP_AXIS) -> Dict[str, P]:
     Head-axis sharding is contiguous, so a shard's Hk/m KV heads arrive
     with ALL of their ``rep = H // Hk`` query heads (queries are laid out
     head-major) — the per-shard kernel body is the unchanged single-device
-    kernel on a contiguous head slice.  ``out_chunk`` is the prefill
+    kernel on a contiguous head slice.  The pool's merged (Hk*D) lane axis
+    is head-major too, so its m contiguous lane ranges are exactly those
+    head slices.  ``out_chunk`` is the prefill
     output AFTER its (B, S, H, D) -> (B, S, H*D) head-major flatten, so
     concatenating shards on the last axis restores the full head order.
     """
@@ -377,9 +385,9 @@ def paged_attn_specs(axis: str = TP_AXIS) -> Dict[str, P]:
         "q_decode": P(None, axis, None),        # (B, H, D) head groups
         "q_chunk": P(None, None, axis, None),   # (B, S, H, D)
         "new_kv": P(None, None, axis, None),    # (B, S, Hk, D) chunk K/V
-        "pool": P(None, None, axis, None),      # (N, bs, Hk, D)
-        "scale": P(None, None, axis),           # (N, bs, Hk) SCLAD scales
-        "host": P(),                            # tables/lengths/starts
+        "pool": P(None, None, None, axis),      # (L, N, bs, Hk*D)
+        "scale": P(None, None, None, axis),     # (L, N, bs, Hk) SCLAD scales
+        "host": P(),                            # tables/lengths/starts/layer
         "out_decode": P(None, axis, None),      # (B, H, D)
         "out_chunk": P(None, None, axis),       # (B, S, H*D) head-major
     }

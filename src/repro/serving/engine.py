@@ -407,7 +407,7 @@ class ServingEngine:
         admit decisions) runs unreplicated on this process, and the mesh
         only widens the jitted device work.  What shards over the mesh's
         ``model`` axis: the weights (``param_specs(mode="serve")``), the
-        paged KV pool's KV-head axis — payload AND SCLAD scale leaves,
+        paged KV pool's KV heads — payload AND SCLAD scale leaves,
         co-placed by ``cache_specs(paged=True)`` — and, through the
         ``shard_map`` wrappers in ``kernels.*.ops``, the attention heads
         of both paged hot paths.  What broadcasts: block tables, lengths/
@@ -1143,7 +1143,7 @@ class ServingEngine:
                                  prefix_cache=self.prefix_cache,
                                  kv_dtype=cfg.kv_dtype)
         # +1 device block: id 0 is the dead-lane trash sink.  With a mesh
-        # the pool lands pre-sharded on its KV-head axis (payload + scale
+        # the pool lands pre-sharded on its KV heads (payload + scale
         # leaves co-placed) so the shard_map'd kernels read it in place.
         self._cache = M.init_paged_cache(cfg, self.num_blocks + 1, bs,
                                          mesh=self._mesh)

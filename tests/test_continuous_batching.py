@@ -235,7 +235,9 @@ def test_prefill_slots_paged_primitives(tiny):
         max_len=MAX_LEN)
     np.testing.assert_array_equal(np.asarray(logits[0]),
                                   np.asarray(ref_logits[0]))
-    gathered = k[:, owned].reshape(k.shape[0], 2 * bs, *k.shape[3:])
+    # Pool rows are lane-dense: (Hk*D) lanes per token, head-major.
+    gathered = k[:, owned].reshape(k.shape[0], 2 * bs, cfg.num_kv_heads,
+                                   cfg.head_dim)
     np.testing.assert_array_equal(
         gathered[:, :len(prompt)],
         np.asarray(ref_cache["k"][:, 0, :len(prompt)], np.float32))

@@ -96,15 +96,28 @@ def test_flash_decode_per_row_lengths():
 # paged flash decode (block-pool layout, tables via scalar prefetch)
 # ---------------------------------------------------------------------------
 
+def _stacked(x, layers=1):
+    """(N, bs, Hk, D) random blocks -> the lane-dense (L, N, bs, Hk*D)
+    layout ``model.init_paged_cache`` stores: the same values in layer 0,
+    fresh ones in any further layer."""
+    N, bs, Hk, D = x.shape
+    more = [jax.random.normal(jax.random.PRNGKey(100 + i), x.shape)
+            .astype(x.dtype) for i in range(layers - 1)]
+    return jnp.stack([x] + more).reshape(layers, N, bs, Hk * D)
+
+
 def _build_pool(rng_seed, B, Hk, D, bs, T, lengths, dtype,
-                dead_lanes=()):
-    """A shared pool + per-row tables: unique blocks per live row in random
-    pool order, TRASH (0) for unallocated tails and for dead lanes."""
+                dead_lanes=(), layers=1):
+    """A shared stacked pool + per-row tables: unique blocks per live row
+    in random pool order, TRASH (0) for unallocated tails and for dead
+    lanes."""
     n_blocks = 1 + sum(-(-int(l) // bs) for l in lengths)
     N = n_blocks + 2  # a couple of never-referenced blocks
     ks = jax.random.split(jax.random.PRNGKey(rng_seed), 3)
-    k_pool = jax.random.normal(ks[0], (N, bs, Hk, D)).astype(dtype)
-    v_pool = jax.random.normal(ks[1], (N, bs, Hk, D)).astype(dtype)
+    k_pool = _stacked(jax.random.normal(ks[0], (N, bs, Hk, D)).astype(dtype),
+                      layers)
+    v_pool = _stacked(jax.random.normal(ks[1], (N, bs, Hk, D)).astype(dtype),
+                      layers)
     rng = np.random.default_rng(rng_seed)
     free = list(rng.permutation(np.arange(1, N)))
     tables = np.zeros((B, T), np.int32)
@@ -196,9 +209,10 @@ def test_paged_flash_decode_shared_blocks():
 # ---------------------------------------------------------------------------
 
 def _build_prefill_case(seed, B, H, Hk, D, bs, T, prefix, P, starts, lengths,
-                        dtype, share_ctx_block=False):
-    """Chunk tensors + a shared pool + per-row tables covering each row's
-    cached context and write span (unique blocks in random pool order)."""
+                        dtype, share_ctx_block=False, layers=1):
+    """Chunk tensors + a shared stacked pool + per-row tables covering each
+    row's cached context and write span (unique blocks in random pool
+    order)."""
     S = prefix + P
     sv = np.zeros(B, np.int64) if starts is None else np.asarray(starts)
     first_extra = prefix if starts is None else 0
@@ -209,8 +223,10 @@ def _build_prefill_case(seed, B, H, Hk, D, bs, T, prefix, P, starts, lengths,
     q = jax.random.normal(ks[0], (B, S, H, D)).astype(dtype)
     kn = jax.random.normal(ks[1], (B, S, Hk, D)).astype(dtype)
     vn = jax.random.normal(ks[2], (B, S, Hk, D)).astype(dtype)
-    kp = jax.random.normal(ks[3], (N, bs, Hk, D)).astype(dtype)
-    vp = jax.random.normal(ks[4], (N, bs, Hk, D)).astype(dtype)
+    kp = _stacked(jax.random.normal(ks[3], (N, bs, Hk, D)).astype(dtype),
+                  layers)
+    vp = _stacked(jax.random.normal(ks[4], (N, bs, Hk, D)).astype(dtype),
+                  layers)
     rng = np.random.default_rng(seed)
     free = list(rng.permutation(np.arange(1, N)))
     tables = np.zeros((B, T), np.int32)
@@ -317,12 +333,18 @@ def test_paged_flash_prefill_chunk_sweep(bs, P):
 # SCLAD quantized KV pools (int8/fp8 payload + per-position fp32 scales)
 # ---------------------------------------------------------------------------
 
-def _quantize_pool(kp, vp, kv_dtype):
-    """Compress a dense (N, bs, Hk, D) pool the way the engine stores it:
-    per-position-per-head payload + fp32 scales (``models.kv_quant``)."""
+def _quantize_pool(kp, vp, kv_dtype, D):
+    """Compress a dense (L, N, bs, Hk*D) pool the way the engine stores
+    it: per-position-per-head payload + fp32 scales (``models.kv_quant``),
+    payload lane-dense, scales (L, N, bs, Hk)."""
     from repro.models import kv_quant
-    kq, ks = kv_quant.quantize(kp, kv_dtype)
-    vq, vs = kv_quant.quantize(vp, kv_dtype)
+
+    def one(x):
+        q, sc = kv_quant.quantize(x.reshape(x.shape[:-1] + (-1, D)),
+                                  kv_dtype)
+        return q.reshape(x.shape), sc
+
+    (kq, ks), (vq, vs) = one(kp), one(vp)
     return kq, vq, ks, vs
 
 
@@ -335,7 +357,7 @@ def test_paged_flash_decode_quantized(kv_dtype, dtype):
     lengths = np.asarray([T * bs, 1, 13], np.int32)
     k_pool, v_pool, tables = _build_pool(31, B, Hk, D, bs, T, lengths,
                                          jnp.float32)
-    kq, vq, ks, vs = _quantize_pool(k_pool, v_pool, kv_dtype)
+    kq, vq, ks, vs = _quantize_pool(k_pool, v_pool, kv_dtype, D)
     q = jax.random.normal(jax.random.PRNGKey(37), (B, H, D)).astype(dtype)
     out = paged_flash_decode(q, kq, vq, jnp.asarray(lengths), tables,
                              kv_scales=(ks, vs), interpret=True)
@@ -354,7 +376,8 @@ def _check_prefill_parity_quantized(case, prefix, dtype, kv_dtype):
     q, kn, vn, kp, vp, lengths, tables, st = case
     B = q.shape[0]
     kq, vq, ks, vs = _quantize_pool(kp.astype(jnp.float32),
-                                    vp.astype(jnp.float32), kv_dtype)
+                                    vp.astype(jnp.float32), kv_dtype,
+                                    q.shape[-1])
     ro, rk, rv, rks, rvs = prefill_attention_ref(
         q, kn, vn, kq, vq, lengths, tables, start=st, prefix=prefix,
         kv_scales=(ks, vs), kv_dtype=kv_dtype)
@@ -409,7 +432,7 @@ def test_quantized_scatter_path_independent():
                                [P], jnp.bfloat16)
     q, kn, vn, kp, vp, lengths, tables, st = case
     kq, vq, ks, vs = _quantize_pool(kp.astype(jnp.float32),
-                                    vp.astype(jnp.float32), "int8")
+                                    vp.astype(jnp.float32), "int8", D)
     _, k1, v1, ks1, vs1 = prefill_attention_ref(
         q, kn, vn, kq, vq, lengths, tables, start=st,
         kv_scales=(ks, vs), kv_dtype="int8")
@@ -432,6 +455,68 @@ def test_quantized_scatter_path_independent():
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(pools[1]))
     np.testing.assert_array_equal(np.asarray(ks1), np.asarray(pools[2]))
     np.testing.assert_array_equal(np.asarray(vs1), np.asarray(pools[3]))
+
+
+# ---------------------------------------------------------------------------
+# one layer of a stacked pool: the layer index rides the scalar prefetch
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_flash_decode_at_layer_of_stacked_pool(kv_dtype):
+    """The kernel reads the slab of the layer it is given (here 2 of a
+    3-layer pool, through the scalar-prefetched index), matching the
+    reference at that layer and differing from the other layers'."""
+    B, H, Hk, D, bs, T, layer = 3, 8, 2, 64, 8, 4, 2
+    lengths = np.asarray([T * bs, 1, 13], np.int32)
+    k_pool, v_pool, tables = _build_pool(59, B, Hk, D, bs, T, lengths,
+                                         jnp.float32, layers=3)
+    scales = None
+    if kv_dtype == "int8":
+        k_pool, v_pool, *scales = _quantize_pool(k_pool, v_pool, "int8", D)
+        scales = tuple(scales)
+    q = jax.random.normal(jax.random.PRNGKey(61), (B, H, D))
+    out = paged_flash_decode(q, k_pool, v_pool, jnp.asarray(lengths), tables,
+                             jnp.int32(layer), kv_scales=scales,
+                             interpret=True)
+    for other in range(3):
+        ref = paged_decode_ref(q, k_pool, v_pool, jnp.asarray(lengths),
+                               tables, kv_scales=scales, layer=other)
+        close = np.allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                            rtol=2e-5)
+        assert close == (other == layer), other
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_flash_prefill_at_layer_of_stacked_pool(kv_dtype):
+    """The kernel reads context from, and writes the chunk's K/V in place
+    into, the layer it is given (1 of a 3-layer pool): output and every
+    pool leaf match the reference at that layer, and the other layers'
+    bytes are left bit-identical."""
+    B, H, Hk, D, bs, T, P, layer = 3, 8, 2, 64, 8, 4, 8, 1
+    rng = np.random.default_rng(67)
+    starts = [bs] + [1 + int(rng.integers(0, (T - 1) * bs - P))
+                     for _ in range(B - 1)]
+    lengths = [P] + [int(rng.integers(1, P + 1)) for _ in range(B - 1)]
+    q, kn, vn, kp, vp, lengths, tables, st = _build_prefill_case(
+        71, B, H, Hk, D, bs, T, 0, P, starts, lengths, jnp.float32,
+        layers=3)
+    pools, quant = (kp, vp), None
+    if kv_dtype == "int8":
+        pools, quant = _quantize_pool(kp, vp, "int8", D), "int8"
+    scales = tuple(pools[2:]) or None
+    ref = prefill_attention_ref(q, kn, vn, pools[0], pools[1], lengths,
+                                tables, start=st, kv_scales=scales,
+                                kv_dtype=quant, layer=layer)
+    got = paged_flash_prefill(q, kn, vn, pools[0], pools[1], lengths, tables,
+                              st, jnp.int32(layer), interpret=True,
+                              kv_scales=scales, kv_dtype=quant)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
+                               atol=2e-5, rtol=2e-5)
+    for before, r, g in zip(pools, ref[1:], got[1:]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+        changed = [not np.array_equal(np.asarray(g[i]), np.asarray(before[i]))
+                   for i in range(3)]
+        assert changed == [i == layer for i in range(3)], changed
 
 
 # ---------------------------------------------------------------------------

@@ -320,7 +320,11 @@ def test_quantized_pool_leaves_and_bytes():
     assert set(cache) == {"k", "v", "k_scale", "v_scale"}
     assert cache["k"].dtype == jnp.int8
     assert cache["k_scale"].dtype == jnp.float32
-    assert cache["k_scale"].shape == cache["k"].shape[:-1]
+    # Lane-dense payload rows (Hk*D) and one scale per (position, head).
+    assert cache["k"].shape == (cfg.num_layers, 5, 4,
+                                cfg.num_kv_heads * cfg.head_dim)
+    assert cache["k_scale"].shape == cache["k"].shape[:-1] \
+        + (cfg.num_kv_heads,)
     # copy_cache_block moves payload AND scales.
     cache = {k: (v + 1 if v.dtype != jnp.int8 else v + jnp.int8(1))
              for k, v in cache.items()}

@@ -3,7 +3,8 @@
 Parity contract: sharding the paged KV pool's kv-head axis (payload AND
 SCLAD scale leaves) changes NO arithmetic — attention is independent per
 KV head, so each shard reads its contiguous Hk/m pool slice with its
-matching query head group and outputs concat back on the head axis.
+matching query head group and outputs concat back on the head axis (the
+pool is lane-dense, so a shard's heads are a contiguous lane range).
 Pinned here at three levels:
 
   * kernel level — ``decode_attention`` / ``prefill_attention`` with a
@@ -61,20 +62,26 @@ def _mesh(m):
     return Mesh(devs, ("data", "model"))
 
 
+#: Pool layers, and the layer the kernel-level cases read and write.
+LAYERS, LAYER = 2, 1
+
+
 def _paged_inputs(rng_seed, B, H, Hk, D, N, bs, dtype=jnp.float32,
                   quantized=False):
+    """A stacked lane-dense (LAYERS, N, bs, Hk*D) pool and its inputs."""
     ks = jax.random.split(jax.random.PRNGKey(rng_seed), 5)
     q = jax.random.normal(ks[0], (B, H, D)).astype(dtype)
+    rows = (LAYERS, N, bs, Hk * D)
     if quantized:
-        kc = jax.random.randint(ks[1], (N, bs, Hk, D), -127, 128, jnp.int8)
-        vc = jax.random.randint(ks[2], (N, bs, Hk, D), -127, 128, jnp.int8)
-        scales = (jax.random.uniform(ks[3], (N, bs, Hk), jnp.float32,
+        kc = jax.random.randint(ks[1], rows, -127, 128, jnp.int8)
+        vc = jax.random.randint(ks[2], rows, -127, 128, jnp.int8)
+        scales = (jax.random.uniform(ks[3], rows[:3] + (Hk,), jnp.float32,
                                      0.01, 0.1),
-                  jax.random.uniform(ks[4], (N, bs, Hk), jnp.float32,
+                  jax.random.uniform(ks[4], rows[:3] + (Hk,), jnp.float32,
                                      0.01, 0.1))
     else:
-        kc = jax.random.normal(ks[1], (N, bs, Hk, D)).astype(dtype)
-        vc = jax.random.normal(ks[2], (N, bs, Hk, D)).astype(dtype)
+        kc = jax.random.normal(ks[1], rows).astype(dtype)
+        vc = jax.random.normal(ks[2], rows).astype(dtype)
         scales = None
     # Non-overlapping per-row tables walking the whole pool.
     T = N // B
@@ -89,25 +96,29 @@ def _paged_inputs(rng_seed, B, H, Hk, D, N, bs, dtype=jnp.float32,
 
 @needs2
 def test_attn_shard_size_matches_sanitize_gate():
-    """The dispatch gate and the placement sanitizer must agree: shard
-    exactly when the mesh has a model axis >1 that divides Hk."""
+    """The dispatch gate and the pool's placement must agree: shard
+    exactly when the mesh has a model axis >1 that divides Hk — also where
+    the merged Hk*D lane axis would divide it."""
     mesh = _mesh(2)
     assert sharding.attn_shard_size(None, 4) == 1
     assert sharding.attn_shard_size(mesh, 4) == 2
     assert sharding.attn_shard_size(mesh, 3) == 1  # 3 % 2 != 0 -> solo
+    cfg = dataclasses.replace(get_config("tinyllama-1.1b").reduced(),
+                              num_heads=3, num_kv_heads=3)
+    cache = M.init_paged_cache(cfg, 2, 8)
+    assert cache["k"].shape[-1] % 2 == 0  # 3 heads x 16 lanes
     with sharding.use_axes(mesh):
         spec = sharding.sanitize_specs(
-            P(None, None, None, "model", None),
-            jax.ShapeDtypeStruct((2, 8, 8, 3, 16), jnp.float32))
-    assert spec[3] is None  # sanitizer drops it for the same Hk
+            sharding.cache_specs(cfg, cache, None, 1, paged=True), cache)
+    assert spec["k"][3] is None  # placement drops it for the same Hk
 
 
 def test_paged_attn_specs_shapes():
     sp = sharding.paged_attn_specs()
-    # Kernel-level pools are the 4-D (N, bs, Hk, D) slices (one layer);
-    # the 5-D (L, ...) placement rule lives in cache_specs(paged=True).
-    assert sp["pool"] == P(None, None, "model", None)
-    assert sp["scale"] == P(None, None, "model")
+    # Kernels take the whole stacked (L, N, bs, Hk*D) pool: heads are
+    # contiguous lane ranges of its last axis, as in cache_specs(paged=True).
+    assert sp["pool"] == P(None, None, None, "model")
+    assert sp["scale"] == P(None, None, None, "model")
     assert sp["q_decode"] == P(None, "model", None)
     assert sp["host"] == P()
 
@@ -123,10 +134,10 @@ def test_sharded_decode_matches_single(kernel, quantized):
     q, kc, vc, lengths, tables, scales = _paged_inputs(
         0, B=2, H=8, Hk=4, D=16, N=8, bs=8, quantized=quantized)
     solo = decode_ops.decode_attention(
-        q, kc, vc, lengths, block_tables=tables, kernel=kernel,
+        q, kc, vc, lengths, block_tables=tables, layer=LAYER, kernel=kernel,
         kv_scales=scales, mesh=None)
     shard = decode_ops.decode_attention(
-        q, kc, vc, lengths, block_tables=tables, kernel=kernel,
+        q, kc, vc, lengths, block_tables=tables, layer=LAYER, kernel=kernel,
         kv_scales=scales, mesh=_mesh(2))
     # float32 per-head math is untouched by the split: bitwise equal.
     np.testing.assert_array_equal(np.asarray(solo), np.asarray(shard))
@@ -137,9 +148,11 @@ def test_sharded_decode_bf16_within_kernel_tolerance():
     q, kc, vc, lengths, tables, _ = _paged_inputs(
         1, B=2, H=4, Hk=2, D=16, N=8, bs=8, dtype=jnp.bfloat16)
     solo = decode_ops.decode_attention(q, kc, vc, lengths,
-                                       block_tables=tables, mesh=None)
+                                       block_tables=tables, layer=LAYER,
+                                       mesh=None)
     shard = decode_ops.decode_attention(q, kc, vc, lengths,
-                                        block_tables=tables, mesh=_mesh(2))
+                                        block_tables=tables, layer=LAYER,
+                                        mesh=_mesh(2))
     np.testing.assert_allclose(
         np.asarray(solo, np.float32), np.asarray(shard, np.float32),
         atol=tol(jnp.bfloat16), rtol=tol(jnp.bfloat16))
@@ -152,9 +165,11 @@ def test_indivisible_heads_fall_back_to_single_path():
     q, kc, vc, lengths, tables, _ = _paged_inputs(
         2, B=1, H=3, Hk=3, D=8, N=4, bs=8)
     solo = decode_ops.decode_attention(q, kc, vc, lengths,
-                                       block_tables=tables, mesh=None)
+                                       block_tables=tables, layer=LAYER,
+                                       mesh=None)
     shard = decode_ops.decode_attention(q, kc, vc, lengths,
-                                        block_tables=tables, mesh=_mesh(2))
+                                        block_tables=tables, layer=LAYER,
+                                        mesh=_mesh(2))
     np.testing.assert_array_equal(np.asarray(solo), np.asarray(shard))
 
 
@@ -177,7 +192,7 @@ def test_sharded_prefill_matches_single(kernel, quantized, continuation):
     lengths = jnp.array([S, S - 3], jnp.int32)
     start = jnp.array([bs, bs], jnp.int32) if continuation else None
     kv_dtype = "int8" if quantized else None
-    kw = dict(start=start, kernel=kernel, kv_scales=scales,
+    kw = dict(layer=LAYER, start=start, kernel=kernel, kv_scales=scales,
               kv_dtype=kv_dtype)
     solo = prefill_ops.prefill_attention(
         q, k_new, v_new, kp, vp, lengths, tables, mesh=None, **kw)
@@ -276,7 +291,8 @@ def test_sharded_decode_never_allgathers_pool():
 
     def step(q, kc, vc, lengths, tables):
         return decode_ops.decode_attention(q, kc, vc, lengths,
-                                           block_tables=tables, mesh=mesh)
+                                           block_tables=tables, layer=LAYER,
+                                           mesh=mesh)
 
     hlo = jax.jit(step).lower(q, kc, vc, lengths, tables) \
         .compile().as_text()
@@ -304,7 +320,7 @@ def test_cache_specs_cosharded_payload_and_scales(kv_dtype):
                               kv_dtype=kv_dtype)
     cache = M.init_paged_cache(cfg, 2, 8)
     specs = sharding.cache_specs(cfg, cache, None, 1, paged=True)
-    assert specs["k"] == P(None, None, None, "model", None)
+    assert specs["k"] == P(None, None, None, "model")
     assert specs["v"] == specs["k"]
     if kv_quant.is_quantized(kv_dtype):
         assert set(cache) == {"k", "v", "k_scale", "v_scale"}
